@@ -46,7 +46,6 @@ import time
 import numpy as np
 
 import jax.numpy as jnp
-import jax._src.test_util as jtu
 
 from repro.core.lear import LearClassifier
 from repro.core.strategies import QueryExitConfig
@@ -59,6 +58,7 @@ from repro.serve.errors import Overloaded
 from repro.serve.ranking_service import RankingService, ServiceConfig
 from repro.serve.tier import ServingTier, TierConfig
 from repro.serve.warmup import warmup_service
+from repro.utils import count_lowerings
 
 JSON_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_serve.json")
 
@@ -247,12 +247,12 @@ def run_degraded_quality(n_trees: int, smoke: bool) -> tuple[list[dict], int]:
 
     Xj, mj = jnp.asarray(X), jnp.asarray(mask)
     per_level: list[np.ndarray] = []
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_lowerings() as count:
         for level in range(svc.n_rungs):
             svc.set_rung(level)
             _top, scores = svc.rank_batch(Xj, mj)
             per_level.append(np.asarray(scores))
-    lowerings = int(count[0])
+    lowerings = count()
 
     names = ["baseline"] + [r.name for r in DEGRADE_RUNGS]
     rungs = [

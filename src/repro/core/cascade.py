@@ -340,6 +340,55 @@ class CascadeRanker:
                 f"the deprecated keywords, not both (got {sorted(legacy)})"
             )
 
+        step, args = self._progressive_step(
+            X, mask, config, stage_ema, have_ema, query_exit_rate,
+            strategy_kwargs,
+        )
+        (scores, alive, stage_masks, partials, overflow, sp, picked,
+         q_exited) = step(*args)
+        return CascadeResult(
+            scores=scores,
+            continue_mask=alive,
+            speedup=sp,
+            overflow=overflow,   # lazy: no device sync
+            stage_masks=list(stage_masks),
+            partials=partials,
+            mode=config.mode,
+            picked_staged=picked,  # lazy device bool (auto), else None
+            query_exited=q_exited if config.query_exit is not None else None,
+        )
+
+    def lower_progressive(
+        self,
+        X: jax.Array,
+        mask: jax.Array,
+        config: EngineConfig,
+        *,
+        stage_ema: jax.Array | None = None,
+        have_ema: jax.Array | bool = True,
+        query_exit_rate: jax.Array | float = 0.0,
+        **strategy_kwargs: object,
+    ) -> jax.stages.Lowered:
+        """The step :meth:`rank_progressive` runs for these arguments,
+        lowered and not executed (``.compile().as_text()`` shows the
+        program the device runs)."""
+        step, args = self._progressive_step(
+            X, mask, config, stage_ema, have_ema, query_exit_rate,
+            strategy_kwargs,
+        )
+        return step.lower(*args)
+
+    def _progressive_step(
+        self,
+        X: jax.Array,
+        mask: jax.Array,
+        config: EngineConfig,
+        stage_ema: jax.Array | None,
+        have_ema: jax.Array | bool,
+        query_exit_rate: jax.Array | float,
+        strategy_kwargs: dict,
+    ) -> tuple[Callable[..., tuple], tuple]:
+        """The cached jitted step for ``config`` and its call arguments."""
         Q, D, F = X.shape
         dense = config.dense
         tree_sentinels = config.sentinels
@@ -392,13 +441,16 @@ class CascadeRanker:
             leaf_gather=config.leaf_gather,
         )
 
-        # Array-valued strategy kwargs become traced operands of the jitted
-        # step; everything else (ints, floats, flags) is static config and
-        # part of the cache key.
+        # Array-valued strategy kwargs (or their shapes, when lowering)
+        # become traced operands of the jitted step; everything else (ints,
+        # floats, flags) is static config and part of the cache key.
         names = tuple(sorted(strategy_kwargs))
         traced_names = tuple(
             n for n in names
-            if isinstance(strategy_kwargs[n], (jax.Array, np.ndarray))
+            if isinstance(
+                strategy_kwargs[n],
+                (jax.Array, np.ndarray, jax.ShapeDtypeStruct),
+            )
         )
         static_items = tuple(
             (n, strategy_kwargs[n]) for n in names if n not in traced_names
@@ -451,19 +503,7 @@ class CascadeRanker:
             self._step_cache.move_to_end(key)
 
         traced_vals = tuple(strategy_kwargs[n] for n in traced_names)
-        (scores, alive, stage_masks, partials, overflow, sp, picked,
-         q_exited) = step(X, mask, traced_vals, mode_ops)
-        return CascadeResult(
-            scores=scores,
-            continue_mask=alive,
-            speedup=sp,
-            overflow=overflow,   # lazy: no device sync
-            stage_masks=list(stage_masks),
-            partials=partials,
-            mode=run_mode,
-            picked_staged=picked,  # lazy device bool (auto), else None
-            query_exited=q_exited if config.query_exit is not None else None,
-        )
+        return step, (X, mask, traced_vals, mode_ops)
 
 
 _STEP_CACHE_MAX = 16  # compiled progressive steps kept per ranker (LRU)

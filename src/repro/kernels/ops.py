@@ -57,6 +57,17 @@ once — while at run time only the branch the device pick selects actually
 dispatches. So the auto-step contract is ``segmented == 1`` and
 ``plain == S+2`` (with a tail region; S ≥ 2) per trace, stable across
 re-executions regardless of which branch each batch takes.
+
+Row sharding
+------------
+Under a mesh context (``jax.set_mesh``, entered by
+:class:`repro.serve.placement.ServePlacement`) whose :data:`ROW_AXIS` axis
+spans several devices, every launch runs under ``jax.shard_map`` over
+that axis: each device's kernel scores its own contiguous share of the
+document rows. The kernel scores every row independently, so the result
+is bit-identical to one device; without the ``shard_map`` the SPMD
+partitioner cannot split a Mosaic kernel and would run the whole batch on
+every device.
 """
 
 from __future__ import annotations
@@ -69,6 +80,7 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.kernels.forest_score import (
     _next_pow2,
@@ -81,6 +93,10 @@ if typing.TYPE_CHECKING:  # annotation-only: keeps ops importable before
 
 LANE = 128
 ALL_ONES = np.uint32(0xFFFFFFFF)
+
+#: Mesh axis the kernel launches split their document rows over — the
+#: query axis of :func:`repro.serve.placement.data_parallel`.
+ROW_AXIS = "data"
 
 
 def env_int(name: str, default: int, *, minimum: int = 1) -> int:
@@ -162,19 +178,33 @@ def launch_counts() -> dict[str, int]:
     return dict(_LAUNCH_COUNTS)
 
 
+def _row_shards() -> int:
+    """Devices the context mesh splits document rows over (1: no mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else dict(mesh.shape).get(ROW_AXIS, 1)
+
+
 def _counted_pallas(
     kind: str, call: typing.Callable[..., jax.Array],
-    *args: object, **kwargs: object,
+    x: jax.Array, *tables: jax.Array, **kwargs: object,
 ) -> jax.Array:
     """Counting ``pallas_call`` wrapper: record the launch at staging time.
 
-    ``call`` is one of the (jitted) Pallas entry points. The counter bumps
-    when this wrapper's Python body runs — per call in eager execution, per
-    trace under an enclosing ``jit`` — so counts reflect launches staged
-    into each computation, and stay stable across cached re-executions.
+    ``call`` is one of the (jitted) Pallas entry points, ``x`` its
+    document rows. The counter bumps when this wrapper's Python body runs —
+    per call in eager execution, per trace under an enclosing ``jit`` — so
+    counts reflect launches staged into each computation, and stay stable
+    across cached re-executions. Under a row-sharding mesh the launch runs
+    per device on its share of the rows (module docstring).
     """
     _LAUNCH_COUNTS[kind] += 1
-    return call(*args, **kwargs)
+    if _row_shards() == 1:
+        return call(x, *tables, **kwargs)
+    rows = PartitionSpec(ROW_AXIS)
+    return jax.shard_map(
+        lambda xs: call(xs, *tables, **kwargs),
+        in_specs=rows, out_specs=rows, check_vma=False,
+    )(x)
 
 
 def _pad_to(
@@ -342,9 +372,13 @@ def _build_padded_forest(
 
 
 def _prep_x(X: jax.Array, block_b: int) -> tuple[jax.Array, int]:
-    B = X.shape[0]
-    block_b = effective_block_b(block_b, B)
-    x = _pad_to(X.astype(jnp.float32), 0, block_b)
+    """Pad ``X`` for a launch: rows to whole doc blocks on every row
+    shard, features to whole lanes. Returns the padded rows and the
+    per-device doc-block size."""
+    shards = _row_shards()
+    per_shard = -(-X.shape[0] // shards)
+    block_b = effective_block_b(block_b, per_shard)
+    x = _pad_to(X.astype(jnp.float32), 0, block_b * shards)
     x = _pad_to(x, 1, LANE)
     return x, block_b
 

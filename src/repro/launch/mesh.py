@@ -14,24 +14,32 @@ import.
 
 from __future__ import annotations
 
+from collections.abc import Sequence
+
 import jax
+from jax.sharding import AxisType
 
 
-def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; older runtimes use the
-    # default (Auto) axis semantics implicitly.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def make_mesh(
+    shape: Sequence[int],
+    axes: Sequence[str],
+    devices: Sequence[jax.Device] | None = None,
+) -> jax.sharding.Mesh:
+    """Mesh with ``Auto`` axes: the SPMD partitioner shards the program
+    from its operands' shardings (jax's default axis type is ``Explicit``,
+    under which a ``pallas_call`` outside ``shard_map`` is refused)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh() -> jax.sharding.Mesh:
     """1×1 mesh over the single local device — CPU tests of the mesh path."""
-    return _make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

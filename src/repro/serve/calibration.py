@@ -74,8 +74,10 @@ def calibrate_launch_overhead_trees(
     Returns the calibrated ``launch_overhead_trees`` for the current jax
     backend. Degenerate measurements (non-positive per-tree slope, e.g. on
     a noisy box where the small launch out-timed the big one) fall back to
-    :data:`DEFAULT_LAUNCH_OVERHEAD_TREES`. With ``record_path`` the probe
-    merges its report under ``"launch_calibration"`` into that JSON file —
+    :data:`DEFAULT_LAUNCH_OVERHEAD_TREES`, and the report says so
+    (``"fell_back": true`` in :func:`last_calibration`). With
+    ``record_path`` the probe merges its report under
+    ``"launch_calibration"`` into that JSON file —
     an operator-facing hook for deployments that track the value out of
     band. The kernel bench does NOT use it (its ``main()`` rewrites
     ``BENCH_kernels.json`` wholesale); it embeds :func:`last_calibration`
@@ -105,7 +107,8 @@ def calibrate_launch_overhead_trees(
     )
 
     per_doctree = (t_full - t_small) / max(n_docs * (n_trees - block_t), 1)
-    if per_doctree <= 0:
+    fell_back = per_doctree <= 0
+    if fell_back:
         overhead = DEFAULT_LAUNCH_OVERHEAD_TREES
     else:
         launch_us = max(t_small - per_doctree * n_docs * block_t, 0.0)
@@ -120,6 +123,7 @@ def calibrate_launch_overhead_trees(
         "t_full_us": round(t_full, 1),
         "per_doctree_us": round(per_doctree, 6),
         "launch_overhead_trees": overhead,
+        "fell_back": fell_back,  # degenerate probe → the default above
     }
     _CALIBRATION_CACHE[key] = payload
     if record_path is not None:

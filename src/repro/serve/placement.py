@@ -18,13 +18,15 @@ cross-check against it.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.distributed.sharding import Rules, single_pod_rules
-from repro.launch.mesh import make_local_mesh
+from repro.kernels.ops import ROW_AXIS
+from repro.launch.mesh import make_local_mesh, make_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +50,13 @@ class ServePlacement:
         for ax in axes:
             n *= self.mesh.shape[ax]
         return n
+
+    def scope(self) -> contextlib.AbstractContextManager:
+        """Context the engine step is traced and run in: the mesh (row
+        sharding of the forest launches), or nothing for one device."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     def put(
         self, X: jax.Array, mask: jax.Array
@@ -86,7 +95,7 @@ def data_parallel(n_devices: int | None = None) -> ServePlacement:
     devs = jax.devices()
     n = len(devs) if n_devices is None else n_devices
     assert 1 <= n <= len(devs), (n, len(devs))
-    mesh = jax.make_mesh((n, 1), ("data", "model"), devices=devs[:n])
+    mesh = make_mesh((n, 1), (ROW_AXIS, "model"), devices=devs[:n])
     return ServePlacement(mesh=mesh, rules=single_pod_rules())
 
 

@@ -46,6 +46,7 @@ the ``sentinel_fn`` / ``full_fn`` hooks — see examples/cascade_retrieval.py.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import typing
@@ -593,45 +594,20 @@ class RankingService:
         by the padded batch shape ``(Q, D)`` — each serving bucket adapts
         to its own traffic.
 
-        ``placement`` (a :class:`repro.serve.placement.ServePlacement`, or
-        anything with ``.put(X, mask)``) pins the operands to a device
-        mesh before submit; ``None`` is the single-device fast path and
-        is bit-exact with any 1-device placement.
+        ``placement`` (a :class:`repro.serve.placement.ServePlacement`)
+        pins the operands to a device mesh before submit and holds the
+        mesh in context while the step runs; ``None`` is the
+        single-device fast path and is bit-exact with any 1-device
+        placement.
         """
         if placement is not None:
             X, mask = placement.put(X, mask)
         Q, D, _ = X.shape
-        self._active_key = (Q, D)
-        n_docs = Q * D
-        capacities = self._pick_capacities(n_docs)
-        mode = self.execution_mode
-        extra = {}
-        if mode == "auto":
-            if len(self.sentinels) == 1:
-                mode = "fused"  # S=1: both modes are the same computation
-            else:
-                # Ship the survivor estimate at submit; the pick happens
-                # inside the compiled step. Cold start (no observed rates
-                # yet): have_ema=False forces the fused branch. The EMA
-                # covers ALL stages (dense entry first for hybrid).
-                ema = self._stage_ema or [float(n_docs)] * self.n_stages
-                extra = dict(
-                    stage_ema=jnp.asarray(ema, jnp.float32),
-                    have_ema=self._stage_ema is not None,
-                    query_exit_rate=jnp.asarray(
-                        self._query_exit_rate_estimate(), jnp.float32
-                    ),
-                )
-        engine_config = EngineConfig(
-            stages=self._engine_stage_tuple(),
-            mode=mode,
-            capacities=tuple(capacities),
-            launch_overhead_trees=self.launch_overhead_trees,
-            query_exit=self.query_exit,
-        )
-        result = self.cascade.rank_progressive(
-            X, mask, engine_config, features=X, **extra,
-        )
+        mode, engine_config, extra = self._engine_call(Q, D)
+        with _scope(placement):
+            result = self.cascade.rank_progressive(
+                X, mask, engine_config, features=X, **extra,
+            )
         # Top-k is the response (clamped to the candidate count — a small
         # query block must not crash top_k).
         masked = jnp.where(mask, result.scores, -jnp.inf)
@@ -706,6 +682,66 @@ class RankingService:
         s.trees_full_equiv += int(batch_docs) * T
 
         return top_idx, scores
+
+    def lower_step(
+        self,
+        X: jax.Array,
+        mask: jax.Array,
+        placement: ServePlacement | None = None,
+    ) -> jax.stages.Lowered:
+        """The engine step :meth:`rank_batch` runs for this batch, lowered
+        and not executed — for inspecting the compiled program (for
+        example, that its forest launches are Mosaic kernels, each fed its
+        own device's rows)."""
+        if placement is not None:
+            X, mask = placement.put(X, mask)
+        Q, D, _ = X.shape
+        _mode, engine_config, extra = self._engine_call(Q, D)
+        with _scope(placement):
+            return self.cascade.lower_progressive(
+                X, mask, engine_config, features=X, **extra,
+            )
+
+    def _engine_call(
+        self, Q: int, D: int
+    ) -> tuple[str, EngineConfig, dict]:
+        """Mode, engine config and traced mode operands for one ``(Q, D)``
+        batch, from that bucket's adaptive state."""
+        self._active_key = (Q, D)
+        n_docs = Q * D
+        capacities = self._pick_capacities(n_docs)
+        mode = self.execution_mode
+        extra = {}
+        if mode == "auto":
+            if len(self.sentinels) == 1:
+                mode = "fused"  # S=1: both modes are the same computation
+            else:
+                # Ship the survivor estimate at submit; the pick happens
+                # inside the compiled step. Cold start (no observed rates
+                # yet): have_ema=False forces the fused branch. The EMA
+                # covers ALL stages (dense entry first for hybrid).
+                ema = self._stage_ema or [float(n_docs)] * self.n_stages
+                extra = dict(
+                    stage_ema=jnp.asarray(ema, jnp.float32),
+                    have_ema=self._stage_ema is not None,
+                    query_exit_rate=jnp.asarray(
+                        self._query_exit_rate_estimate(), jnp.float32
+                    ),
+                )
+        engine_config = EngineConfig(
+            stages=self._engine_stage_tuple(),
+            mode=mode,
+            capacities=tuple(capacities),
+            launch_overhead_trees=self.launch_overhead_trees,
+            query_exit=self.query_exit,
+        )
+        return mode, engine_config, extra
+
+
+def _scope(
+    placement: ServePlacement | None,
+) -> contextlib.AbstractContextManager:
+    return contextlib.nullcontext() if placement is None else placement.scope()
 
 
 @dataclasses.dataclass

@@ -59,7 +59,8 @@ class TierConfig:
     """Frozen bundle of the :class:`ServingTier` deployment knobs.
 
     The tier-level mirror of :class:`repro.serve.ranking_service.ServiceConfig`
-    — what to warm, whether to warm, and where compiled artifacts persist.
+    — what to warm, whether to warm, and whether compiled artifacts persist
+    (where: :func:`repro.serve.warmup.enable_persistent_cache`).
     ``policy`` and ``placement`` stay direct constructor arguments: they
     are live objects (thread-owning batcher policy, device mesh), not
     declarative configuration.
@@ -68,7 +69,6 @@ class TierConfig:
     doc_counts: tuple[int, ...] = (64,)
     warmup: bool = True
     persistent_cache: bool = True
-    cache_dir: str | None = None
     degradation: DegradationPolicy | None = None
 
     def __post_init__(self) -> None:
@@ -95,7 +95,6 @@ class ServingTier:
         doc_counts: Sequence[int] | None = None,
         warmup: bool | None = None,
         persistent_cache: bool | None = None,
-        cache_dir: str | None = None,
     ) -> None:
         if config is not None and not isinstance(config, TierConfig):
             # Legacy POSITIONAL call: ServingTier(svc, F, (64, 256), …)
@@ -106,7 +105,6 @@ class ServingTier:
             for name, value in (
                 ("doc_counts", doc_counts), ("warmup", warmup),
                 ("persistent_cache", persistent_cache),
-                ("cache_dir", cache_dir),
             )
             if value is not None
         }
@@ -129,7 +127,6 @@ class ServingTier:
         self.doc_counts = config.doc_counts
         self.do_warmup = config.warmup
         self.persistent_cache = config.persistent_cache
-        self.cache_dir = config.cache_dir
         self.warmup_report: WarmupReport | None = None
         self.degradation = (
             DegradationController(service, config.degradation, clock=clock)
@@ -144,10 +141,7 @@ class ServingTier:
 
     def start(self) -> ServingTier:
         assert not self._started, "tier already started"
-        cache_dir = (
-            enable_persistent_cache(self.cache_dir)
-            if self.persistent_cache else None
-        )
+        cache_dir = enable_persistent_cache() if self.persistent_cache else None
         if self.degradation is not None and self.service.n_rungs == 0:
             # Materialize every rung BEFORE warmup so the warmup pass
             # below AOT-compiles the whole ladder — degrading at peak

@@ -22,15 +22,16 @@ Two cold-start costs stand between service start and steady-state latency:
 
 Across process restarts the same trace is a cache hit on disk:
 :func:`enable_persistent_cache` points jax's persistent compilation cache
-at a directory (default ``$REPRO_COMPILE_CACHE`` or a per-user temp dir),
-so restart warmup replays compiled artifacts instead of re-invoking XLA.
+at ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else at
+:data:`DEFAULT_CACHE_DIR` inside the checkout, so restart warmup replays
+compiled artifacts instead of re-invoking XLA.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import os
-import tempfile
+import pathlib
 import time
 import typing
 from collections.abc import Sequence
@@ -45,24 +46,26 @@ if typing.TYPE_CHECKING:  # annotation-only: avoids a serve-package cycle
 
 DEFAULT_WARMUP_BUCKETS = ((1, 64), (4, 64), (8, 64))
 
+#: Compile-cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset: one
+#: fixed, git-ignored directory inside the checkout — a cache only hits
+#: again at the same path.
+DEFAULT_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+)
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if needed). Thresholds are dropped to "cache everything" — serving
-    steps are small but latency-critical. Returns the directory actually
-    configured, or ``None`` if the runtime lacks the cache config (the
-    service must start regardless)."""
-    if cache_dir is None:
-        cache_dir = os.environ.get("REPRO_COMPILE_CACHE") or os.path.join(
-            tempfile.gettempdir(), f"repro-xla-cache-{os.getuid()}"
-        )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (OSError, AttributeError, ValueError):
-        return None
+
+def enable_persistent_cache() -> str:
+    """Point jax's persistent compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` if set, else at :data:`DEFAULT_CACHE_DIR`
+    (created if needed), and return that directory. Thresholds are dropped
+    to "cache everything" — serving steps are small but latency-critical.
+    A directory that cannot be created raises: a service that silently
+    ran with the cache off would pay every compile on each restart."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
 
 

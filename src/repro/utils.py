@@ -1,4 +1,13 @@
-"""Instrumentation helpers shared by tests and benchmarks.
+"""Instrumentation helpers shared by tests, benchmarks and ``chip_smoke.py``.
+
+:func:`count_lowerings` counts jit lowerings (jaxpr → MLIR module) in a
+block, on every thread — the served window of a warmed tier must show
+zero. It listens on jax's public monitoring event for the lowering, so
+lowerings on the batcher's worker thread count too.
+
+:func:`mosaic_kernel_rows` reads a compiled program's Mosaic kernel
+launches and the rows each is fed: proof that the device runs compiled
+kernels (not interpret mode) on its own share of the batch.
 
 :func:`count_host_transfers` is the device-residency guard: it counts
 device→host materializations of jax arrays while a block runs, split into
@@ -24,10 +33,58 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import re
 import threading
 
 import jax
 from jax._src import array as _jax_array
+
+#: jax.monitoring duration event recorded once per jaxpr → MLIR lowering.
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+@contextlib.contextmanager
+def count_lowerings():
+    """Count jit lowerings while the ``with`` block runs, on any thread.
+
+    Yields a callable returning the count so far (read it inside or after
+    the block)::
+
+        with count_lowerings() as lowerings:
+            serve()
+        assert lowerings() == 0
+    """
+    lock = threading.Lock()
+    count = [0]
+
+    def listener(event: str, duration: float, **kwargs: object) -> None:
+        if event == LOWERING_EVENT:
+            with lock:
+                count[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield lambda: count[0]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
+_MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
+_ROWS = re.compile(r"=\s*f32\[(\d+),\d+\]")
+
+
+def mosaic_kernel_rows(hlo_text: str) -> list[int]:
+    """Row count of each Mosaic kernel launch (``tpu_custom_call``) in a
+    compiled program's HLO text — per device, in a partitioned program.
+    Every forest launch returns ``f32[rows, segments]``."""
+    rows = []
+    for line in hlo_text.splitlines():
+        if _MOSAIC_CALL in line:
+            m = _ROWS.search(line)
+            if m is None:
+                raise ValueError(f"unparsed kernel launch: {line[:200]}")
+            rows.append(int(m.group(1)))
+    return rows
 
 
 @dataclasses.dataclass

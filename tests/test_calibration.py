@@ -52,6 +52,7 @@ def test_probe_solves_scripted_timings(monkeypatch):
     report = calibration.last_calibration()
     assert report["launch_overhead_trees"] == pytest.approx(800.0)
     assert report["per_doctree_us"] == pytest.approx(1.25)
+    assert report["fell_back"] is False
 
 
 def test_degenerate_probe_falls_back_to_default(monkeypatch):
@@ -60,6 +61,7 @@ def test_degenerate_probe_falls_back_to_default(monkeypatch):
     _fake_clock(monkeypatch, [0.0, 5000e-6, 1.0, 1.0 + 5000e-6])
     got = calibration.calibrate_launch_overhead_trees(**PROBE)
     assert got == calibration.DEFAULT_LAUNCH_OVERHEAD_TREES
+    assert calibration.last_calibration()["fell_back"] is True
 
 
 def test_calibration_cached_per_process(monkeypatch):

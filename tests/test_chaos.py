@@ -27,7 +27,6 @@ import numpy as np
 import pytest
 
 import jax.numpy as jnp
-import jax._src.test_util as jtu
 
 from faults import (
     CrashTimes,
@@ -55,6 +54,7 @@ from repro.serve.errors import (
 )
 from repro.serve.ranking_service import RankingService, ServiceConfig
 from repro.serve.warmup import warmup_service
+from repro.utils import count_lowerings
 
 pytestmark = pytest.mark.chaos
 
@@ -426,8 +426,8 @@ def test_rung_warmup_leaves_zero_post_warmup_lowerings():
 
     X = jnp.asarray(_query(32, seed=5)[None])
     mask = jnp.ones((1, 32), bool)
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_lowerings() as lowerings:
         for level in (0, 1, 2, 1, 0):
             svc.set_rung(level)
             svc.rank_batch(X, mask)
-    assert count[0] == 0, f"{count[0]} recompiles while stepping rungs"
+    assert lowerings() == 0, f"{lowerings()} recompiles while stepping rungs"

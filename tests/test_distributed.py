@@ -7,6 +7,8 @@ SPMD train step in a subprocess (XLA device count is process-global, so the
 multi-device case cannot run in-process).
 """
 
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -26,12 +28,7 @@ from repro.distributed.sharding import (
 from repro.launch.mesh import make_local_mesh
 from repro.train.elastic import remesh, validate_divisibility
 
-# jax.sharding.set_mesh / AxisType landed after 0.4.x; tests that depend on
-# the newer explicit-mesh API are capability-skipped on older runtimes.
-requires_set_mesh = pytest.mark.skipif(
-    not hasattr(jax.sharding, "set_mesh"),
-    reason="jax.sharding.set_mesh not available in this jax version",
-)
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_rules_resolution():
@@ -50,7 +47,6 @@ def test_constrain_noop_without_rules():
     np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-@requires_set_mesh
 def test_constrain_under_local_mesh():
     mesh = make_local_mesh()
     with sharding_rules(single_pod_rules()), jax.sharding.set_mesh(mesh):
@@ -111,13 +107,12 @@ print("SPMD_OK", loss)
 """
 
 
-@requires_set_mesh
 def test_8device_spmd_train_step():
     res = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_PROG],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo",
+        env={"PYTHONPATH": str(REPO / "src"), "PATH": os.environ["PATH"],
+             "JAX_PLATFORMS": "cpu"},
+        cwd=REPO,
     )
     assert "SPMD_OK" in res.stdout, res.stdout + res.stderr

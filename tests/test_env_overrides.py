@@ -10,12 +10,23 @@ needs a fresh interpreter, so it runs in a subprocess — same idiom as the
 multi-device check in test_distributed.
 """
 
+import os
+import pathlib
 import subprocess
 import sys
 
 import pytest
 
 from repro.kernels.ops import env_int
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _env(**overrides: str) -> dict[str, str]:
+    """A fresh interpreter's environment: this checkout's sources on the
+    CPU, plus the overrides under test."""
+    return {"PYTHONPATH": str(REPO / "src"), "PATH": os.environ["PATH"],
+            "JAX_PLATFORMS": "cpu", **overrides}
 
 
 def test_env_int_default_when_unset(monkeypatch):
@@ -94,14 +105,12 @@ def test_override_path_end_to_end():
     res = subprocess.run(
         [sys.executable, "-c", _OVERRIDE_PROG],
         capture_output=True, text=True, timeout=300,
-        env={
-            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-            "JAX_PLATFORMS": "cpu",
-            "REPRO_PADDED_CACHE_MAX": "2",
-            "REPRO_LEAF_SELECT_MAX": "16",
-            "REPRO_RANK_BLOCKED_MIN_D": "32",
-        },
-        cwd="/root/repo",
+        env=_env(
+            REPRO_PADDED_CACHE_MAX="2",
+            REPRO_LEAF_SELECT_MAX="16",
+            REPRO_RANK_BLOCKED_MIN_D="32",
+        ),
+        cwd=REPO,
     )
     assert "OVERRIDES_OK" in res.stdout, res.stdout + res.stderr
 
@@ -142,15 +151,13 @@ def test_dense_override_path_end_to_end():
     res = subprocess.run(
         [sys.executable, "-c", _DENSE_OVERRIDE_PROG],
         capture_output=True, text=True, timeout=300,
-        env={
-            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-            "JAX_PLATFORMS": "cpu",
-            "REPRO_DENSE_N_VEC": "3",
-            "REPRO_DENSE_VEC_DIM": "8",
-            "REPRO_DENSE_HIDDEN": "12",
-            "REPRO_DENSE_COST_TREES": "9",
-        },
-        cwd="/root/repo",
+        env=_env(
+            REPRO_DENSE_N_VEC="3",
+            REPRO_DENSE_VEC_DIM="8",
+            REPRO_DENSE_HIDDEN="12",
+            REPRO_DENSE_COST_TREES="9",
+        ),
+        cwd=REPO,
     )
     assert "DENSE_OVERRIDES_OK" in res.stdout, res.stdout + res.stderr
 
@@ -160,12 +167,10 @@ def test_dense_n_vec_minimum_enforced():
     res = subprocess.run(
         [sys.executable, "-c", "import repro.models.dense_scorer"],
         capture_output=True, text=True, timeout=300,
-        env={
-            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-            "JAX_PLATFORMS": "cpu",
-            "REPRO_DENSE_N_VEC": "1",
-        },
-        cwd="/root/repo",
+        env=_env(
+            REPRO_DENSE_N_VEC="1",
+        ),
+        cwd=REPO,
     )
     assert res.returncode != 0
     assert "REPRO_DENSE_N_VEC must be >= 2" in res.stderr
@@ -176,12 +181,10 @@ def test_bad_override_fails_at_import():
     res = subprocess.run(
         [sys.executable, "-c", "import repro.kernels.ops"],
         capture_output=True, text=True, timeout=300,
-        env={
-            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-            "JAX_PLATFORMS": "cpu",
-            "REPRO_LEAF_SELECT_MAX": "sixty-four",
-        },
-        cwd="/root/repo",
+        env=_env(
+            REPRO_LEAF_SELECT_MAX="sixty-four",
+        ),
+        cwd=REPO,
     )
     assert res.returncode != 0
     assert "REPRO_LEAF_SELECT_MAX must be an integer" in res.stderr

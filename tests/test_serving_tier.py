@@ -11,6 +11,8 @@ The tentpole claims, as tests:
   is numerically indistinguishable from it.
 """
 
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -19,7 +21,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-import jax._src.test_util as jtu
 
 from repro.core.lear import LearClassifier
 from repro.forest.ensemble import random_ensemble
@@ -28,9 +29,15 @@ from repro.serve.errors import BatcherStopped
 from repro.serve.placement import local, single_device
 from repro.serve.ranking_service import RankingService, ServiceConfig
 from repro.serve.tier import ServingTier, TierConfig
-from repro.serve.warmup import enable_persistent_cache, warmup_service
+from repro.serve.warmup import (
+    DEFAULT_CACHE_DIR,
+    enable_persistent_cache,
+    warmup_service,
+)
+from repro.utils import count_lowerings
 
 F = 12
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _service(seed=0, sentinels=(8, 28), **knobs):
@@ -145,10 +152,10 @@ def test_warmup_no_recompiles_no_cold_start_overflow():
     X = np.random.default_rng(2).normal(size=(2, 64, F)).astype(np.float32)
     X[..., 0] = 1.0  # every document survives every stage
     X, mask = jnp.asarray(X), jnp.ones((2, 64), bool)
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with count_lowerings() as lowerings:
         svc.rank_batch(X, mask)
         svc.rank_batch(X, mask)
-    assert count[0] == 0, f"{count[0]} recompiles after warmup"
+    assert lowerings() == 0, f"{lowerings()} recompiles after warmup"
     assert svc.stats.overflow_docs == 0
     # Without warmup the same dense batch DOES overflow its cold-start
     # capacity — the guarantee above is the warmup, not the workload.
@@ -231,11 +238,11 @@ mask = jnp.ones((8, 16), bool)
 Xs, ms = pl.put(X, mask)
 # The query axis really is split 8 ways...
 assert len(Xs.sharding.device_set) == 8, Xs.sharding
-top_s, sc_s = service().rank_batch(Xs, ms)
+top_s, sc_s = service().rank_batch(Xs, ms, placement=pl)
 # ...and a non-divisible Q degrades to replication instead of crashing.
 X1, m1 = pl.put(X[:1], mask[:1])
 assert len(X1.sharding.device_set) == 8  # replicated across the mesh
-service().rank_batch(X1, m1)
+service().rank_batch(X1, m1, placement=pl)
 
 top_r, sc_r = service().rank_batch(*single_device().put(X, mask))
 np.testing.assert_allclose(np.asarray(sc_s), np.asarray(sc_r),
@@ -252,23 +259,31 @@ def test_data_parallel_placement_8_devices():
         [sys.executable, "-c", _MULTIDEV_PROG],
         capture_output=True, text=True, timeout=570,
         env={
-            "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+            "PYTHONPATH": str(REPO / "src"), "PATH": os.environ["PATH"],
             "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         },
-        cwd="/root/repo",
+        cwd=REPO,
     )
     assert "MULTIDEV_OK" in res.stdout, res.stdout + res.stderr
 
 
-def test_enable_persistent_cache_points_jax_at_dir(tmp_path):
+def test_enable_persistent_cache_points_jax_at_dir(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins; unset, the cache is the one fixed,
+    git-ignored directory inside the checkout."""
     before = jax.config.jax_compilation_cache_dir
     try:
         d = str(tmp_path / "xla-cache")
-        got = enable_persistent_cache(d)
-        assert got == d
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        assert enable_persistent_cache() == d
         assert jax.config.jax_compilation_cache_dir == d
-        import os
         assert os.path.isdir(d)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = enable_persistent_cache()
+        assert got == DEFAULT_CACHE_DIR == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        ignored = (REPO / ".gitignore").read_text().splitlines()
+        assert ".jax_cache/" in ignored
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
