@@ -134,6 +134,17 @@ _DEPRECATED_KWARGS_MSG = (
 )
 
 
+#: ``jax.named_scope`` names of the compiled step's LEAR stages: they land
+#: in each op's ``op_name`` metadata (device traces, compiled HLO) and
+#: change no instruction name. Features and classifier are opened by the
+#: serving path's LEAR strategy (``RankingService._make_strategy``).
+SCOPE_HEAD = "lear/head"
+SCOPE_FEATURES = "lear/features"
+SCOPE_CLASSIFIER = "lear/classifier"
+SCOPE_COMPACTION = "lear/compaction"
+SCOPE_TAIL = "lear/tail"
+
+
 def bucket_capacity(want: int, limit: int, minimum: int = 64) -> int:
     """Power-of-two capacity bucketing (bounded jit cache), clipped to limit."""
     cap = 1 << int(np.ceil(np.log2(max(want, minimum, 1))))
@@ -575,21 +586,25 @@ def _build_progressive_step(
         if not has_tail:
             return scores, overflow
         cap = capacities[-1]
-        sel, n_cont = compact_indices_cumsum(alive.reshape(-1), cap)
-        if query_exit is None:
-            x_sel = jnp.take(flat, sel, axis=0)
-            tail_sel = forest_score_range(pf, x_sel, seg_lo=S)
-            scores = _scatter_tail(scores, sel, tail_sel, n_cont)
-        else:
-            def run_tail(s):
+        with jax.named_scope(SCOPE_COMPACTION):
+            sel, n_cont = compact_indices_cumsum(alive.reshape(-1), cap)
+
+        def run_tail(s, count_as="plain"):
+            with jax.named_scope(SCOPE_COMPACTION):
                 x_sel = jnp.take(flat, sel, axis=0)
+            with jax.named_scope(SCOPE_TAIL):
                 tail_sel = forest_score_range(
-                    pf, x_sel, seg_lo=S, count_as="gated"
+                    pf, x_sel, seg_lo=S, count_as=count_as
                 )
+            with jax.named_scope(SCOPE_COMPACTION):
                 return _scatter_tail(s, sel, tail_sel, n_cont)
 
+        if query_exit is None:
+            scores = run_tail(scores)
+        else:
             scores = jax.lax.cond(
-                n_cont > 0, run_tail, lambda s: s, scores
+                n_cont > 0, lambda s: run_tail(s, "gated"), lambda s: s,
+                scores,
             )
         overflow = overflow + jnp.maximum(n_cont - cap, 0)
         return scores, overflow
@@ -623,13 +638,14 @@ def _build_progressive_step(
         alive = mask & dense.policy(d_scores, mask)
         exited = jnp.zeros((Q,), bool)
         alive, exited = apply_query_exit(0, d_scores, alive, exited)
-        sel, n_cont, within = compact_indices_cumsum_masked(
-            alive.reshape(Q * D), cap
-        )
-        overflow = jnp.maximum(n_cont - cap, 0)
-        alive = alive & within.reshape(Q, D)
-        x_sel = jnp.take(flat, sel, axis=0)
-        valid = jnp.arange(cap) < n_cont
+        with jax.named_scope(SCOPE_COMPACTION):
+            sel, n_cont, within = compact_indices_cumsum_masked(
+                alive.reshape(Q * D), cap
+            )
+            overflow = jnp.maximum(n_cont - cap, 0)
+            alive = alive & within.reshape(Q, D)
+            x_sel = jnp.take(flat, sel, axis=0)
+            valid = jnp.arange(cap) < n_cont
         return d_scores, alive, exited, overflow, sel, x_sel, valid
 
     def scatter_grid(vec, sel, valid, alive, fallback):
@@ -638,19 +654,21 @@ def _build_progressive_step(
         # fallback elsewhere (policies are mask-invariant, so stale slots
         # are never read where it matters).
         Q, D = fallback.shape
-        grid = jnp.zeros((Q * D,), jnp.float32).at[sel].add(
-            jnp.where(valid, vec, 0.0)
-        ).reshape(Q, D)
-        return jnp.where(alive, grid, fallback)
+        with jax.named_scope(SCOPE_COMPACTION):
+            grid = jnp.zeros((Q * D,), jnp.float32).at[sel].add(
+                jnp.where(valid, vec, 0.0)
+            ).reshape(Q, D)
+            return jnp.where(alive, grid, fallback)
 
     def fused_tree_prefix_vecs(x_sel):
         # One launch over the head trees: prefix score of every survivor
         # at every sentinel, as compacted [C] vectors. A single segment
         # needs no segmented accumulator — it degenerates to the plain
         # kernel (same launch count, less work).
-        if S == 1:
-            return [forest_score_range(pf, x_sel, 0, 1)]
-        seg = forest_score_segments(pf, x_sel, n_segments=S)
+        with jax.named_scope(SCOPE_HEAD):
+            if S == 1:
+                return [forest_score_range(pf, x_sel, 0, 1)]
+            seg = forest_score_segments(pf, x_sel, n_segments=S)
         acc = seg[:, 0] + pf.base_score
         vecs = [acc]
         for k in range(1, S):
@@ -665,11 +683,15 @@ def _build_progressive_step(
             alive = mask
             exited = jnp.zeros((Q,), bool)
             stage_masks = []
+            with jax.named_scope(SCOPE_HEAD):
+                if S == 1:
+                    head = forest_score_range(pf, flat, 0, 1)
+                else:
+                    head = forest_score_segments(pf, flat, n_segments=S)
             if S == 1:
-                prefixes = [forest_score_range(pf, flat, 0, 1).reshape(Q, D)]
+                prefixes = [head.reshape(Q, D)]
             else:
-                seg = forest_score_segments(pf, flat, n_segments=S)
-                seg = seg.reshape(Q, D, S)
+                seg = head.reshape(Q, D, S)
                 acc = seg[..., 0] + pf.base_score
                 prefixes = [acc]
                 for k in range(1, S):
@@ -728,7 +750,8 @@ def _build_progressive_step(
             alive = mask
             exited = jnp.zeros((Q,), bool)
             overflow = jnp.int32(0)
-            prefix = forest_score_range(pf, flat, 0, 1).reshape(Q, D)
+            with jax.named_scope(SCOPE_HEAD):
+                prefix = forest_score_range(pf, flat, 0, 1).reshape(Q, D)
             stage_offset = 0
         else:
             d_scores, alive, exited, overflow, sel0, x_sel0, valid0 = (
@@ -737,7 +760,8 @@ def _build_progressive_step(
             # First tree segment on the dense-compacted block — the same
             # block (and therefore the same per-doc kernel sums) the
             # fused head scores, which keeps the modes bit-exact.
-            seg0 = forest_score_range(pf, x_sel0, 0, 1)
+            with jax.named_scope(SCOPE_HEAD):
+                seg0 = forest_score_range(pf, x_sel0, 0, 1)
             prefix = scatter_grid(seg0, sel0, valid0, alive, d_scores)
             stage_offset = 1
         stage_masks = [alive] if dense is not None else []
@@ -750,18 +774,22 @@ def _build_progressive_step(
             )
             if k + 1 < S:
                 cap = tree_caps[k]
-                sel, n_cont, within = compact_indices_cumsum_masked(
-                    alive.reshape(Q * D), cap
-                )
-                overflow = overflow + jnp.maximum(n_cont - cap, 0)
-                alive = alive & within.reshape(Q, D)
-                x_sel = jnp.take(flat, sel, axis=0)
-                seg_sel = forest_score_range(pf, x_sel, k + 1, k + 2)
-                prefix = jnp.where(
-                    alive,
-                    _scatter_tail(prefix, sel, seg_sel, n_cont),
-                    prefix,
-                )
+                with jax.named_scope(SCOPE_COMPACTION):
+                    sel, n_cont, within = compact_indices_cumsum_masked(
+                        alive.reshape(Q * D), cap
+                    )
+                    overflow = overflow + jnp.maximum(n_cont - cap, 0)
+                    alive = alive & within.reshape(Q, D)
+                    x_sel = jnp.take(flat, sel, axis=0)
+                # A middle stage scores trees the fused head scores.
+                with jax.named_scope(SCOPE_HEAD):
+                    seg_sel = forest_score_range(pf, x_sel, k + 1, k + 2)
+                with jax.named_scope(SCOPE_COMPACTION):
+                    prefix = jnp.where(
+                        alive,
+                        _scatter_tail(prefix, sel, seg_sel, n_cont),
+                        prefix,
+                    )
                 prefixes.append(prefix)
             stage_masks.append(alive)
         scores, overflow = final_tail(flat, prefix, alive, overflow)

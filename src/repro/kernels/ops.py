@@ -371,13 +371,25 @@ def _build_padded_forest(
     return pf
 
 
+def _row_blocks(n_rows: int, block_b: int) -> tuple[int, int]:
+    """Per-device doc-block size and row shards of a launch over
+    ``n_rows`` rows on the context mesh."""
+    shards = _row_shards()
+    return effective_block_b(block_b, -(-n_rows // shards)), shards
+
+
+def launch_rows(n_rows: int, block_b: int = ENGINE_BLOCK_B) -> int:
+    """Rows a launch over ``n_rows`` rows scores on the context mesh, the
+    padding :func:`_prep_x` adds included."""
+    block_b, shards = _row_blocks(n_rows, block_b)
+    return -(-n_rows // (block_b * shards)) * block_b * shards
+
+
 def _prep_x(X: jax.Array, block_b: int) -> tuple[jax.Array, int]:
     """Pad ``X`` for a launch: rows to whole doc blocks on every row
     shard, features to whole lanes. Returns the padded rows and the
     per-device doc-block size."""
-    shards = _row_shards()
-    per_shard = -(-X.shape[0] // shards)
-    block_b = effective_block_b(block_b, per_shard)
+    block_b, shards = _row_blocks(X.shape[0], block_b)
     x = _pad_to(X.astype(jnp.float32), 0, block_b * shards)
     x = _pad_to(x, 1, LANE)
     return x, block_b

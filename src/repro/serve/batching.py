@@ -62,7 +62,6 @@ query's scores do not depend on its neighbors in the block.
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import math
 import threading
@@ -90,16 +89,21 @@ from repro.serve.supervisor import (
     SupervisorHealth,
     WorkerSupervisor,
 )
+from repro.serve.tracing import (
+    FLUSH,
+    PACK,
+    PUT,
+    SCATTER,
+    STEP,
+    WAIT,
+    span,
+)
 
 if typing.TYPE_CHECKING:  # annotation-only: placement is constructed by
     from numpy.typing import ArrayLike  # the tier, never by the batcher
 
     from repro.serve.degradation import DegradationController
     from repro.serve.placement import ServePlacement
-
-#: Sliding window of completed-request latencies backing the p50/p99 in
-#: ``health()`` — bounded so introspection can never grow without limit.
-LATENCY_WINDOW = 512
 
 #: Smoothing for the per-bucket observed engine-seconds EMA that feeds
 #: deadline-aware flush scheduling.
@@ -168,7 +172,7 @@ class _Pending:
     flush_at: float        # clock time by which this request must flush
     expires_at: float      # end-to-end deadline (inf = none)
     deadline_ms: float     # as submitted (inf = none), for error messages
-    enqueued_at: float     # clock time of submit, for latency accounting
+    enqueued_at: float     # clock time of submit, for queue-wait accounting
 
 
 @dataclasses.dataclass
@@ -185,6 +189,10 @@ class BatcherStats:
     shed_deadline: int = 0        # submits dead on arrival (budget <= 0)
     expired_deadline: int = 0     # requests that timed out in the queue
     worker_crashes: int = 0       # in-flight buckets lost to worker death
+    queue_wait_s: float = 0.0     # submit → flush start, summed over the
+    queue_waited: int = 0         #   requests handed to an engine call
+    doc_slots: int = 0            # Q_bucket × D_bucket per engine call
+    padded_doc_slots: int = 0     # slots of doc_slots with no real document
 
     @property
     def flushes(self) -> int:
@@ -260,9 +268,7 @@ class ContinuousBatcher:
         self._failed = False
         self._supervisor: WorkerSupervisor | None = None
         self._last_sup_health: SupervisorHealth | None = None
-        self._latencies: collections.deque[float] = collections.deque(
-            maxlen=LATENCY_WINDOW
-        )
+        self._flush_seq = 0  # numbers each flush's spans in a trace
         self._engine_s_ema: dict[int, float] = {}
 
     # -- client side ------------------------------------------------------
@@ -382,11 +388,12 @@ class ContinuousBatcher:
         for db, reqs in sorted(drain.items()):
             for i in range(0, len(reqs), step):
                 self.stats.flushes_drain += 1
-                self._flush(db, reqs[i:i + step])
+                self._flush(db, reqs[i:i + step], "drain")
 
     def health(self) -> dict:
-        """Liveness snapshot: supervisor state + queue depth + latency
-        percentiles over the last :data:`LATENCY_WINDOW` completions."""
+        """Liveness snapshot: supervisor state, queue depth, and
+        ``queue_wait_ms``, the mean time from submit to the start of its
+        flush over every request handed to an engine call so far."""
         sup = (
             self._supervisor.health()
             if self._supervisor is not None
@@ -394,20 +401,14 @@ class ContinuousBatcher:
         )
         with self._cond:
             depth = sum(len(v) for v in self._pending.values())
-            lat = list(self._latencies)
-        p50 = p99 = 0.0
-        if lat:
-            arr = np.asarray(lat, np.float64) * 1e3
-            p50 = float(np.percentile(arr, 50))
-            p99 = float(np.percentile(arr, 99))
+        wait_s, waited = self.stats.queue_wait_s, self.stats.queue_waited
         return {
             "state": sup.state,
             "restarts": sup.restarts,
             "crashes": sup.crashes,
             "last_error": sup.last_error,
             "queue_depth": depth,
-            "p50_ms": p50,
-            "p99_ms": p99,
+            "queue_wait_ms": 1e3 * wait_s / waited if waited else 0.0,
         }
 
     # -- supervision callbacks (guard thread) -----------------------------
@@ -479,7 +480,7 @@ class ContinuousBatcher:
 
     def _run(self) -> None:
         while True:
-            with self._cond:
+            with self._cond, span(WAIT):
                 db = reqs = None
                 while True:
                     now = self._clock.now()
@@ -508,7 +509,7 @@ class ContinuousBatcher:
                 # failure here IS a worker crash (supervisor territory).
                 hooks.on_flush(db, len(reqs))
             t0 = self._clock.now()
-            self._flush(db, reqs)
+            self._flush(db, reqs, trigger)
             elapsed = self._clock.now() - t0
             with self._cond:
                 self._inflight = []
@@ -518,8 +519,13 @@ class ContinuousBatcher:
                     elapsed if prev is None else (1 - a) * prev + a * elapsed
                 )
 
-    def _flush(self, db: int, reqs: list[_Pending]) -> None:
+    def _flush(
+        self, db: int, reqs: list[_Pending], trigger: str = "drain"
+    ) -> None:
         """Score one padded block and scatter per-query results back.
+
+        ``trigger`` (``full``, ``deadline`` or ``drain``) only labels the
+        flush's ``lear.flush`` span.
 
         Failure containment, tightest scope first: an expired request is
         resolved without engine work; a request that cannot even be packed
@@ -530,6 +536,36 @@ class ContinuousBatcher:
         Anything escaping this method is a worker crash for the
         supervisor.
         """
+        self._flush_seq += 1
+        with span(FLUSH, bucket=db, queries=len(reqs), trigger=trigger,
+                  seq=self._flush_seq):
+            with span(PACK):
+                block = self._pack(db, reqs)
+            if block is None:
+                return  # the whole bucket died in the queue: no launch
+            live, X, mask = block
+            try:
+                with span(PUT):
+                    X, mask = jnp.asarray(X), jnp.asarray(mask)
+                with span(STEP):
+                    _, scores = self.service.rank_batch(
+                        X, mask, placement=self.placement
+                    )
+            except Exception as e:
+                # Engine failure: this bucket's futures must not hang, and
+                # the worker loop must survive to serve the next bucket.
+                self._fail_live(live, e)
+                return
+            with span(SCATTER):
+                self._scatter(live, scores)
+
+    def _pack(
+        self, db: int, reqs: list[_Pending]
+    ) -> tuple[list[_Pending | None], np.ndarray, np.ndarray] | None:
+        """Expire dead requests and pack the rest into a padded block:
+        ``(live, X [qb, db, F], mask [qb, db])``, or ``None`` when no
+        request is left. Counts each packed request's queue wait and the
+        block's document slots."""
         now = self._clock.now()
         live: list[_Pending | None] = []
         for r in reqs:
@@ -538,7 +574,7 @@ class ContinuousBatcher:
             else:
                 live.append(r)
         if not live:
-            return  # the whole bucket died in the queue: no engine launch
+            return None
         qb = self.policy.query_bucket(len(live))
         X = np.zeros((qb, db, self.n_features), np.float32)
         mask = np.zeros((qb, db), bool)
@@ -551,18 +587,23 @@ class ContinuousBatcher:
                 mask[i] = False
                 self._fail(r, e)
                 live[i] = None
+        docs = 0
+        for r in live:
+            if r is not None:
+                self.stats.queue_wait_s += now - r.enqueued_at
+                self.stats.queue_waited += 1
+                docs += r.n_docs
         self.stats.padded_query_slots += qb - len(live)
+        self.stats.doc_slots += qb * db
+        self.stats.padded_doc_slots += qb * db - docs
+        return live, X, mask
+
+    def _scatter(self, live: list[_Pending | None], scores: object) -> None:
+        """Each live request's scores and top-k back to its future."""
         try:
-            _, scores = self.service.rank_batch(
-                jnp.asarray(X), jnp.asarray(mask), placement=self.placement
-            )
             scores = np.asarray(scores)
         except Exception as e:
-            # Engine failure: this bucket's futures must not hang, and the
-            # worker loop must survive to serve the next bucket.
-            for r in live:
-                if r is not None:
-                    self._fail(r, e)
+            self._fail_live(live, e)
             return
         hooks = self.hooks
         for i, r in enumerate(live):
@@ -577,12 +618,18 @@ class ContinuousBatcher:
                 top = np.lexsort((np.arange(r.n_docs), -s))[:k]
                 r.future.set_result((top.astype(np.int32), s))
                 self.stats.completed += 1
-                self._latencies.append(self._clock.now() - r.enqueued_at)
             except Exception as e:
                 # Poisoned scatter: one request fails, bucket-mates don't.
                 self._fail(r, e)
 
     # -- resolution helpers -----------------------------------------------
+
+    def _fail_live(
+        self, live: list[_Pending | None], exc: BaseException
+    ) -> None:
+        for r in live:
+            if r is not None:
+                self._fail(r, exc)
 
     def _fail(self, r: _Pending, exc: BaseException) -> None:
         if not r.future.done():

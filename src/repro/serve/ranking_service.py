@@ -49,6 +49,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import time
 import typing
 import warnings
 from collections.abc import Callable, Sequence
@@ -62,17 +63,23 @@ if typing.TYPE_CHECKING:  # annotation-only: avoids a serve-package cycle
     from repro.serve.degradation import ExitRung
     from repro.serve.placement import ServePlacement
 
-from repro.core.cascade import CascadeRanker, bucket_capacity
+from repro.core.cascade import (
+    SCOPE_CLASSIFIER,
+    SCOPE_FEATURES,
+    CascadeRanker,
+    bucket_capacity,
+)
 from repro.core.lear import LearClassifier, augment_features
 from repro.core.stage import DenseStage, EngineConfig, TreeStage
 from repro.core.strategies import QueryExitConfig, dense_keep_fraction
 from repro.forest.ensemble import TreeEnsemble
-from repro.kernels.ops import ENGINE_BLOCK_B
+from repro.kernels.ops import ENGINE_BLOCK_B, launch_rows
 from repro.metrics.speedup import (
     progressive_cost_model,
     trees_traversed_progressive,
 )
 from repro.serve.calibration import calibrate_launch_overhead_trees
+from repro.serve.tracing import ADAPT, DISPATCH, POST, READ, span
 
 _DEPRECATED_SERVICE_MSG = (
     "repro.serve.ranking_service.RankingService: keyword configuration "
@@ -181,6 +188,13 @@ class ServiceStats:
     batches_fused: int = 0
     batches_staged: int = 0
     queries_exited: int = 0  # query-level exit fired (query_exit enabled)
+    tail_rows: int = 0  # rows the tail launches scored, block padding included
+    # Host seconds of rank_batch's phases (time.perf_counter): placement,
+    # engine config and the compiled step's dispatch; the eager ops after
+    # it up to the read; the one device_get.
+    dispatch_s: float = 0.0
+    post_s: float = 0.0
+    read_s: float = 0.0
 
     @property
     def speedup(self) -> float:
@@ -393,11 +407,13 @@ class RankingService:
         # degradation rungs pass their own explicit threshold and get their
         # own closure, hence their own compiled step.
         def strategy(partial, mask, features=None):
-            aug = augment_features(features, partial, mask)
+            with jax.named_scope(SCOPE_FEATURES):
+                aug = augment_features(features, partial, mask)
             th = self.threshold if threshold is None else threshold
-            return clf.continue_mask(
-                aug, mask, th, use_kernel=self.use_kernel_classifier
-            )
+            with jax.named_scope(SCOPE_CLASSIFIER):
+                return clf.continue_mask(
+                    aug, mask, th, use_kernel=self.use_kernel_classifier
+                )
 
         return strategy
 
@@ -600,88 +616,111 @@ class RankingService:
         single-device fast path and is bit-exact with any 1-device
         placement.
         """
-        if placement is not None:
-            X, mask = placement.put(X, mask)
-        Q, D, _ = X.shape
-        mode, engine_config, extra = self._engine_call(Q, D)
-        with _scope(placement):
-            result = self.cascade.rank_progressive(
-                X, mask, engine_config, features=X, **extra,
+        t0 = time.perf_counter()
+        with span(DISPATCH):
+            if placement is not None:
+                X, mask = placement.put(X, mask)
+            Q, D, _ = X.shape
+            mode, engine_config, extra = self._engine_call(Q, D)
+            with _scope(placement):
+                result = self.cascade.rank_progressive(
+                    X, mask, engine_config, features=X, **extra,
+                )
+                tail_rows = self._tail_rows(engine_config, Q * D)
+        t1 = time.perf_counter()
+        with span(POST):
+            # Top-k is the response (clamped to the candidate count — a
+            # small query block must not crash top_k).
+            masked = jnp.where(mask, result.scores, -jnp.inf)
+            top_idx = jax.lax.top_k(masked, min(self.top_k, D))[1]
+            T = self.ensemble.n_trees
+            picked_staged = (
+                result.picked_staged
+                if result.picked_staged is not None
+                else mode == "staged"
             )
-        # Top-k is the response (clamped to the candidate count — a small
-        # query block must not crash top_k).
-        masked = jnp.where(mask, result.scores, -jnp.inf)
-        top_idx = jax.lax.top_k(masked, min(self.top_k, D))[1]
-
-        # ONE fused device read: the response (top-k + scores) AND the
-        # stats (per-stage survivors, cost metric, overflow, doc count,
-        # picked branch) — no other host sync anywhere on this path.
-        T = self.ensemble.n_trees
-        picked_staged = (
-            result.picked_staged
-            if result.picked_staged is not None
-            else mode == "staged"
-        )
-        q_exited = (
-            result.query_exited.sum()
-            if result.query_exited is not None
-            else jnp.int32(0)
-        )
-        (top_idx, scores, survivors, traversed, overflow, batch_docs,
-         picked_staged, q_exited) = jax.device_get((
-            top_idx,
-            result.scores,
-            jnp.stack([m.sum() for m in result.stage_masks]),
-            trees_traversed_progressive(
-                mask, result.stage_masks, self._acct_sentinels, T,
-                list(self._acct_classifier_trees),
-            ),
-            result.overflow,
-            mask.sum(),
-            picked_staged,
-            q_exited,
-        ))
-        # Adapt: running max sizes the buckets, the EMA feeds the cost
-        # model. Peaks and EMA seed independently — warmup pre-seeds peaks
-        # (the no-overflow guarantee) but leaves the EMA to real traffic.
-        a = self.survivor_ema
-        state = self._active_state()
-        if state.peaks is None:
-            state.peaks = [int(n) for n in survivors]
-        else:
-            state.peaks = [
-                max(p, int(n)) for p, n in zip(state.peaks, survivors)
-            ]
-        if state.ema is None:
-            state.ema = [float(n) for n in survivors]
-        else:
-            state.ema = [
-                (1 - a) * e + a * float(n)
-                for e, n in zip(state.ema, survivors)
-            ]
-        if self.query_exit is not None:
-            # Zero final-stage survivors ⟺ the gated tail launch was
-            # skipped this batch; its smoothed rate is what the cost
-            # model discounts the tail launch term by next submit.
-            skipped = 1.0 if int(survivors[-1]) == 0 else 0.0
-            if state.tail_skip is None:
-                state.tail_skip = skipped
+            q_exited = (
+                result.query_exited.sum()
+                if result.query_exited is not None
+                else jnp.int32(0)
+            )
+            fetch = (
+                top_idx,
+                result.scores,
+                jnp.stack([m.sum() for m in result.stage_masks]),
+                trees_traversed_progressive(
+                    mask, result.stage_masks, self._acct_sentinels, T,
+                    list(self._acct_classifier_trees),
+                ),
+                result.overflow,
+                mask.sum(),
+                picked_staged,
+                q_exited,
+            )
+        t2 = time.perf_counter()
+        with span(READ):
+            # ONE fused device read: the response (top-k + scores) AND the
+            # stats (per-stage survivors, cost metric, overflow, doc count,
+            # picked branch) — no other host sync anywhere on this path.
+            (top_idx, scores, survivors, traversed, overflow, batch_docs,
+             picked_staged, q_exited) = jax.device_get(fetch)
+        t3 = time.perf_counter()
+        with span(ADAPT):
+            # Adapt: running max sizes the buckets, the EMA feeds the cost
+            # model. Peaks and EMA seed independently — warmup pre-seeds peaks
+            # (the no-overflow guarantee) but leaves the EMA to real traffic.
+            a = self.survivor_ema
+            state = self._active_state()
+            if state.peaks is None:
+                state.peaks = [int(n) for n in survivors]
             else:
-                state.tail_skip = (1 - a) * state.tail_skip + a * skipped
+                state.peaks = [
+                    max(p, int(n)) for p, n in zip(state.peaks, survivors)
+                ]
+            if state.ema is None:
+                state.ema = [float(n) for n in survivors]
+            else:
+                state.ema = [
+                    (1 - a) * e + a * float(n)
+                    for e, n in zip(state.ema, survivors)
+                ]
+            tail_skipped = False
+            if self.query_exit is not None:
+                # Zero final-stage survivors ⟺ the gated tail launch was
+                # skipped this batch; its smoothed rate is what the cost
+                # model discounts the tail launch term by next submit.
+                tail_skipped = int(survivors[-1]) == 0
+                skipped = 1.0 if tail_skipped else 0.0
+                if state.tail_skip is None:
+                    state.tail_skip = skipped
+                else:
+                    state.tail_skip = (1 - a) * state.tail_skip + a * skipped
 
-        s = self.stats
-        s.batches += 1
-        s.batches_staged += bool(picked_staged)
-        s.batches_fused += not bool(picked_staged)
-        s.queries += Q
-        s.docs += int(batch_docs)
-        s.docs_continued += int(survivors[-1])
-        s.overflow_docs += int(overflow)
-        s.queries_exited += int(q_exited)
-        s.trees_traversed += float(traversed)
-        s.trees_full_equiv += int(batch_docs) * T
-
+            s = self.stats
+            s.batches += 1
+            s.batches_staged += bool(picked_staged)
+            s.batches_fused += not bool(picked_staged)
+            s.queries += Q
+            s.docs += int(batch_docs)
+            s.docs_continued += int(survivors[-1])
+            s.overflow_docs += int(overflow)
+            s.queries_exited += int(q_exited)
+            s.trees_traversed += float(traversed)
+            s.trees_full_equiv += int(batch_docs) * T
+            s.tail_rows += 0 if tail_skipped else tail_rows
+            s.dispatch_s += t1 - t0
+            s.post_s += t2 - t1
+            s.read_s += t3 - t2
         return top_idx, scores
+
+    def _tail_rows(self, engine_config: EngineConfig, n_docs: int) -> int:
+        """Rows the tail launch of one ``n_docs``-document batch scores: the
+        last stage's compaction capacity, padded as the launch pads it on
+        the context mesh; 0 with no tail. Call with the placement's mesh
+        in context."""
+        if self.sentinels[-1] >= self.ensemble.n_trees:
+            return 0
+        return launch_rows(min(int(engine_config.capacities[-1]), n_docs))
 
     def lower_step(
         self,

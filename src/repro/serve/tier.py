@@ -196,6 +196,10 @@ class ServingTier:
                 "batches_staged": svc.batches_staged,
                 "queries_exited": svc.queries_exited,
                 "query_exit_rate": svc.query_exit_rate,
+                "tail_rows": svc.tail_rows,
+                "dispatch_s": svc.dispatch_s,
+                "post_s": svc.post_s,
+                "read_s": svc.read_s,
             },
             "warmup_seconds": (
                 self.warmup_report.total_seconds if self.warmup_report else 0.0
@@ -206,9 +210,10 @@ class ServingTier:
     def health(self) -> dict:
         """Liveness snapshot for operators and load balancers: supervisor
         state (``running``/``backoff``/``failed``/…), restart and crash
-        counts, current queue depth, p50/p99 completion latency over the
-        recent window, and — when a degradation ladder is configured —
-        the current rung and its queue-delay EMA."""
+        counts, current queue depth, ``queue_wait_ms`` (mean time from
+        submit to the start of its flush, over every request handed to an
+        engine call), and — when a degradation ladder is configured — the
+        current rung and its queue-delay EMA."""
         h = self.batcher.health()
         h["started"] = self._started
         if self.degradation is not None:

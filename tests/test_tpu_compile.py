@@ -13,6 +13,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.configs import lear_msn1
+from repro.core.cascade import SCOPE_CLASSIFIER, SCOPE_HEAD, SCOPE_TAIL
 from repro.core.lear import LearClassifier
 from repro.forest.ensemble import random_ensemble
 from repro.kernels.forest_score import (
@@ -156,9 +158,9 @@ def test_calibration_probe_launch(one_chip):
         assert _kernel_launches(compiled) == 1
 
 
-def _lear_step_kernel_rows(monkeypatch, ranker, classifier, x_sharding,
-                           Q, D, mesh=None) -> list[int]:
-    """Row count per kernel launch in the compiled LEAR serving step."""
+def _lear_step_hlo(monkeypatch, ranker, classifier, x_sharding,
+                   Q, D, mesh=None) -> str:
+    """The compiled LEAR serving step's HLO text."""
     # The ops layer picks Mosaic over interpret mode from the default
     # backend, which is the CPU here.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -173,7 +175,12 @@ def _lear_step_kernel_rows(monkeypatch, ranker, classifier, x_sharding,
     # The mesh is in context as ServePlacement.scope() holds it there;
     # placement.put() needs attached devices, so the shapes come placed.
     with contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh):
-        return mosaic_kernel_rows(svc.lower_step(X, mask).compile().as_text())
+        return svc.lower_step(X, mask).compile().as_text()
+
+
+def _lear_step_kernel_rows(*args, **kwargs) -> list[int]:
+    """Row count per kernel launch in the compiled LEAR serving step."""
+    return mosaic_kernel_rows(_lear_step_hlo(*args, **kwargs))
 
 
 def test_lear_serving_step_one_chip(monkeypatch, one_chip, ranker,
@@ -197,3 +204,41 @@ def test_lear_serving_step_four_chips_row_sharded(monkeypatch, topo, ranker,
         NamedSharding(mesh, PartitionSpec("data")), 8, 256, mesh=mesh,
     )
     assert rows == [8 * 256 // 4] * 3
+
+
+def _mosaic_launches(hlo: str) -> list[tuple[str, str]]:
+    """``(instruction name, op_name metadata)`` of each Mosaic launch."""
+    out = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = line.strip().split(" = ", 1)[0].lstrip("%")
+            m = re.search(r'op_name="([^"]*)"', line)
+            out.append((name, m.group(1) if m else ""))
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_lear_step_launches_carry_their_stage_scope(monkeypatch, topo,
+                                                    ranker, classifier,
+                                                    chips):
+    """Each launch names its LEAR stage in its ``op_name`` metadata, and
+    keeps the instruction name the device trace's kernel match reads."""
+    if chips == 1:
+        mesh, sharding = None, SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = make_mesh((4, 1), ("data", "model"),
+                         devices=np.asarray(topo.devices[:4]))
+        sharding = NamedSharding(mesh, PartitionSpec("data"))
+    hlo = _lear_step_hlo(monkeypatch, ranker, classifier, sharding, 8, 256,
+                         mesh=mesh)
+    launches = _mosaic_launches(hlo)
+    assert len(launches) == 3
+    scopes = []
+    for name, op_name in launches:
+        assert name.startswith("forest_score_pallas."), name
+        found = [s for s in (SCOPE_HEAD, SCOPE_CLASSIFIER, SCOPE_TAIL)
+                 if f"/{s}/" in op_name]
+        assert len(found) == 1, op_name
+        scopes += found
+    assert sorted(scopes) == sorted(
+        [SCOPE_HEAD, SCOPE_CLASSIFIER, SCOPE_TAIL])
