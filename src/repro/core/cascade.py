@@ -123,6 +123,7 @@ from repro.metrics.speedup import (
     progressive_cost_model_device,
     speedup_progressive,
     speedup_vs_full,
+    trees_traversed_progressive,
 )
 
 _DEPRECATED_KWARGS_MSG = (
@@ -356,7 +357,7 @@ class CascadeRanker:
             strategy_kwargs,
         )
         (scores, alive, stage_masks, partials, overflow, sp, picked,
-         q_exited) = step(*args)
+         q_exited, _traversed) = step(*args)
         return CascadeResult(
             scores=scores,
             continue_mask=alive,
@@ -369,6 +370,34 @@ class CascadeRanker:
             query_exited=q_exited if config.query_exit is not None else None,
         )
 
+    def serve_progressive(
+        self,
+        X: jax.Array,
+        mask: jax.Array,
+        config: EngineConfig,
+        top_k: int,
+        *,
+        stage_ema: jax.Array | None = None,
+        have_ema: jax.Array | bool = True,
+        query_exit_rate: jax.Array | float = 0.0,
+        **strategy_kwargs: object,
+    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+        """:meth:`rank_progressive`'s step with a served batch's response and
+        stats computed after it in the same program: one dispatch, and
+        ``(top_idx, scores, traversed, counts)`` as lazy device arrays.
+
+        ``top_idx`` [Q, min(top_k, D)] orders the masked scores (padding
+        at −inf) by descending score, then ascending index; ``traversed``
+        is the trees-traversed total of the speedup's accounting (a dense
+        gate is a zero-sentinel stage); ``counts`` is one int32 vector of
+        the survivors after each stage, then overflow, the document count,
+        the branch that ran (1 = staged) and the queries exited."""
+        step, args = self._progressive_step(
+            X, mask, config, stage_ema, have_ema, query_exit_rate,
+            strategy_kwargs, top_k,
+        )
+        return step(*args)
+
     def lower_progressive(
         self,
         X: jax.Array,
@@ -378,14 +407,16 @@ class CascadeRanker:
         stage_ema: jax.Array | None = None,
         have_ema: jax.Array | bool = True,
         query_exit_rate: jax.Array | float = 0.0,
+        top_k: int | None = None,
         **strategy_kwargs: object,
     ) -> jax.stages.Lowered:
-        """The step :meth:`rank_progressive` runs for these arguments,
-        lowered and not executed (``.compile().as_text()`` shows the
-        program the device runs)."""
+        """The step :meth:`rank_progressive` (or, with ``top_k``,
+        :meth:`serve_progressive`) runs for these arguments, lowered and
+        not executed (``.compile().as_text()`` shows the program the device
+        runs)."""
         step, args = self._progressive_step(
             X, mask, config, stage_ema, have_ema, query_exit_rate,
-            strategy_kwargs,
+            strategy_kwargs, top_k,
         )
         return step.lower(*args)
 
@@ -398,8 +429,11 @@ class CascadeRanker:
         have_ema: jax.Array | bool,
         query_exit_rate: jax.Array | float,
         strategy_kwargs: dict,
+        top_k: int | None = None,
     ) -> tuple[Callable[..., tuple], tuple]:
-        """The cached jitted step for ``config`` and its call arguments."""
+        """The cached jitted step for ``config`` and its call arguments;
+        with ``top_k``, the served step. A served step is its own cache
+        entry, so its body traces once, into the one program served."""
         Q, D, F = X.shape
         dense = config.dense
         tree_sentinels = config.sentinels
@@ -496,17 +530,20 @@ class CascadeRanker:
             id(pf), config.stages, key_capacities, tree_strategies,
             tree_classifier_trees, run_mode,
             float(config.launch_overhead_trees), config.query_exit,
-            traced_names, static_items,
+            traced_names, static_items, top_k,
         )
         step = self._step_cache.get(key)
         if step is None:
-            step = _build_progressive_step(
+            body = _build_progressive_step(
                 pf, dense, tree_sentinels, resolved, tree_strategies,
                 tree_classifier_trees, run_mode, traced_names,
                 dict(static_items), T,
                 launch_overhead_trees=float(config.launch_overhead_trees),
                 query_exit=config.query_exit,
             )
+            if top_k is not None:
+                body = _served(body, top_k, run_mode)
+            step = jax.jit(body)
             self._step_cache[key] = step
             while len(self._step_cache) > _STEP_CACHE_MAX:
                 self._step_cache.popitem(last=False)
@@ -534,7 +571,8 @@ def _build_progressive_step(
     launch_overhead_trees: float = 0.0,
     query_exit: QueryExitConfig | None = None,
 ) -> Callable[..., tuple]:
-    """Build the end-to-end jitted progressive step for one configuration.
+    """Build the end-to-end progressive step body for one configuration,
+    for the caller to ``jax.jit`` (alone, or :func:`_served`).
 
     Everything static (buffers, stages, capacities, mode) is closed over;
     the returned callable takes ``(X, mask, traced_vals, mode_ops)`` —
@@ -798,7 +836,6 @@ def _build_progressive_step(
             jnp.stack(prefixes, axis=-1), overflow, exited,
         )
 
-    @jax.jit
     def step(X, mask, traced_vals, mode_ops):
         Q, D, F = X.shape
         flat = X.reshape(Q * D, F)
@@ -837,10 +874,35 @@ def _build_progressive_step(
             mask, list(stage_masks), acct_sentinels, n_trees,
             list(acct_costs),
         )
+        traversed = trees_traversed_progressive(
+            mask, list(stage_masks), acct_sentinels, n_trees,
+            list(acct_costs),
+        )
         return (
             scores, alive, stage_masks, partials, overflow, sp, picked,
-            exited,
+            exited, traversed,
         )
+
+    return step
+
+
+def _served(
+    body: Callable[..., tuple], top_k: int, mode: str
+) -> Callable[..., tuple]:
+    """``body`` followed by a served batch's response and stats (see
+    :meth:`CascadeRanker.serve_progressive`), as one callable to jit."""
+
+    def step(X, mask, traced_vals, mode_ops):
+        (scores, _alive, stage_masks, _partials, overflow, _sp, picked,
+         exited, traversed) = body(X, mask, traced_vals, mode_ops)
+        masked = jnp.where(mask, scores, -jnp.inf)
+        top_idx = jax.lax.top_k(masked, min(top_k, mask.shape[-1]))[1]
+        staged = picked if picked is not None else jnp.bool_(mode == "staged")
+        counts = jnp.stack([
+            *(m.sum() for m in stage_masks), overflow, mask.sum(), staged,
+            exited.sum(),
+        ]).astype(jnp.int32)
+        return top_idx, scores, traversed, counts
 
     return step
 

@@ -30,10 +30,12 @@ Production concerns handled here:
   headroom, never below the cold-start estimate, bucketed to powers of
   two so re-jits stay bounded;
 - cost accounting per batch (trees traversed, the paper's own metric) and
-  service-level stats — the ENTIRE host read (top-k response, scores,
-  per-stage survivors, cost, overflow, batch doc count, picked branch) is
-  ONE fused ``jax.device_get``: between batch submit and that read the
-  hot path performs zero device→host transfers (guarded by
+  service-level stats, computed inside the same compiled step as the
+  response — the ENTIRE host read (top-k response, scores, per-stage
+  survivors, cost, overflow, batch doc count, picked branch) is ONE fused
+  ``jax.device_get`` of the step's outputs: one engine call is one
+  program and one read, and between batch submit and that read the hot
+  path performs zero device→host transfers (guarded by
   :func:`repro.utils.count_host_transfers` in the tests);
 - graceful degradation: if survivors exceed capacity, the overflow
   documents keep their sentinel scores (bounded quality loss, never a
@@ -74,10 +76,7 @@ from repro.core.stage import DenseStage, EngineConfig, TreeStage
 from repro.core.strategies import QueryExitConfig, dense_keep_fraction
 from repro.forest.ensemble import TreeEnsemble
 from repro.kernels.ops import ENGINE_BLOCK_B, launch_rows
-from repro.metrics.speedup import (
-    progressive_cost_model,
-    trees_traversed_progressive,
-)
+from repro.metrics.speedup import progressive_cost_model
 from repro.serve.calibration import calibrate_launch_overhead_trees
 from repro.serve.tracing import ADAPT, DISPATCH, POST, READ, span
 
@@ -190,8 +189,8 @@ class ServiceStats:
     queries_exited: int = 0  # query-level exit fired (query_exit enabled)
     tail_rows: int = 0  # rows the tail launches scored, block padding included
     # Host seconds of rank_batch's phases (time.perf_counter): placement,
-    # engine config and the compiled step's dispatch; the eager ops after
-    # it up to the read; the one device_get.
+    # engine config and the compiled step's dispatch; what is left between
+    # it and the read (unpacking only); the one device_get.
     dispatch_s: float = 0.0
     post_s: float = 0.0
     read_s: float = 0.0
@@ -315,17 +314,6 @@ class RankingService:
         # always the baseline configuration.
         self._rungs: tuple[_RungState, ...] | None = None
         self._rung_level = 0
-        if self.dense_stage is not None:
-            self._acct_sentinels = (0, *self.sentinels)
-            self._acct_classifier_trees = (
-                float(self.dense_stage.cost_trees),
-                *(float(c.n_trees) for c in stages),
-            )
-        else:
-            self._acct_sentinels = self.sentinels
-            self._acct_classifier_trees = tuple(
-                float(c.n_trees) for c in stages
-            )
         self.n_stages = len(self.sentinels) + (
             1 if self.dense_stage is not None else 0
         )
@@ -600,11 +588,17 @@ class RankingService:
     ) -> tuple[np.ndarray, np.ndarray]:
         """X: [Q, D, F]; returns (top-k doc indices [Q, k], scores [Q, D]).
 
-        Device-resident end to end: the step is submitted with everything
-        it needs (with ``execution_mode="auto"``, also this bucket's
-        survivor EMA as a tiny f32 operand for the in-program mode pick),
-        and the ONLY device→host transfer is the single fused
-        ``jax.device_get`` at the end — response and stats together.
+        One engine call is one compiled program and one read: the engine
+        step with the response and stats computed after it in the same
+        program (:meth:`repro.core.cascade.CascadeRanker.serve_progressive`:
+        masked top-k, trees traversed, and one int32 vector of per-stage
+        survivors, overflow, document count, picked branch and queries
+        exited). The step is submitted with everything it needs (with
+        ``execution_mode="auto"``, also this bucket's survivor EMA as a
+        tiny f32 operand for the in-program mode pick), and the ONLY
+        device→host transfer is the single fused
+        ``jax.device_get`` of its four outputs. Between dispatch and that
+        read the host only unpacks Python objects.
 
         Adaptation (survivor peaks → capacities, EMA → mode pick) is keyed
         by the padded batch shape ``(Q, D)`` — each serving bucket adapts
@@ -621,51 +615,32 @@ class RankingService:
             if placement is not None:
                 X, mask = placement.put(X, mask)
             Q, D, _ = X.shape
-            mode, engine_config, extra = self._engine_call(Q, D)
+            engine_config, extra = self._engine_call(Q, D)
             with _scope(placement):
-                result = self.cascade.rank_progressive(
-                    X, mask, engine_config, features=X, **extra,
+                out = self.cascade.serve_progressive(
+                    X, mask, engine_config, self.top_k, features=X, **extra,
                 )
                 tail_rows = self._tail_rows(engine_config, Q * D)
         t1 = time.perf_counter()
         with span(POST):
-            # Top-k is the response (clamped to the candidate count — a
-            # small query block must not crash top_k).
-            masked = jnp.where(mask, result.scores, -jnp.inf)
-            top_idx = jax.lax.top_k(masked, min(self.top_k, D))[1]
-            T = self.ensemble.n_trees
-            picked_staged = (
-                result.picked_staged
-                if result.picked_staged is not None
-                else mode == "staged"
-            )
-            q_exited = (
-                result.query_exited.sum()
-                if result.query_exited is not None
-                else jnp.int32(0)
-            )
-            fetch = (
-                top_idx,
-                result.scores,
-                jnp.stack([m.sum() for m in result.stage_masks]),
-                trees_traversed_progressive(
-                    mask, result.stage_masks, self._acct_sentinels, T,
-                    list(self._acct_classifier_trees),
-                ),
-                result.overflow,
-                mask.sum(),
-                picked_staged,
-                q_exited,
-            )
+            # All that is left between dispatch and read: the response and
+            # stats already come out of the one compiled step.
+            top_idx, scores, traversed, counts = out
         t2 = time.perf_counter()
         with span(READ):
             # ONE fused device read: the response (top-k + scores) AND the
-            # stats (per-stage survivors, cost metric, overflow, doc count,
-            # picked branch) — no other host sync anywhere on this path.
-            (top_idx, scores, survivors, traversed, overflow, batch_docs,
-             picked_staged, q_exited) = jax.device_get(fetch)
+            # stats (trees traversed; per-stage survivors, overflow, doc
+            # count, picked branch, queries exited) — no other host sync
+            # anywhere on this path.
+            top_idx, scores, traversed, counts = jax.device_get(
+                (top_idx, scores, traversed, counts)
+            )
         t3 = time.perf_counter()
         with span(ADAPT):
+            survivors = counts[:self.n_stages]
+            overflow, batch_docs, picked_staged, q_exited = (
+                counts[self.n_stages:]
+            )
             # Adapt: running max sizes the buckets, the EMA feeds the cost
             # model. Peaks and EMA seed independently — warmup pre-seeds peaks
             # (the no-overflow guarantee) but leaves the EMA to real traffic.
@@ -706,7 +681,7 @@ class RankingService:
             s.overflow_docs += int(overflow)
             s.queries_exited += int(q_exited)
             s.trees_traversed += float(traversed)
-            s.trees_full_equiv += int(batch_docs) * T
+            s.trees_full_equiv += int(batch_docs) * self.ensemble.n_trees
             s.tail_rows += 0 if tail_skipped else tail_rows
             s.dispatch_s += t1 - t0
             s.post_s += t2 - t1
@@ -735,16 +710,15 @@ class RankingService:
         if placement is not None:
             X, mask = placement.put(X, mask)
         Q, D, _ = X.shape
-        _mode, engine_config, extra = self._engine_call(Q, D)
+        engine_config, extra = self._engine_call(Q, D)
         with _scope(placement):
             return self.cascade.lower_progressive(
-                X, mask, engine_config, features=X, **extra,
+                X, mask, engine_config, top_k=self.top_k, features=X,
+                **extra,
             )
 
-    def _engine_call(
-        self, Q: int, D: int
-    ) -> tuple[str, EngineConfig, dict]:
-        """Mode, engine config and traced mode operands for one ``(Q, D)``
+    def _engine_call(self, Q: int, D: int) -> tuple[EngineConfig, dict]:
+        """Engine config and traced mode operands for one ``(Q, D)``
         batch, from that bucket's adaptive state."""
         self._active_key = (Q, D)
         n_docs = Q * D
@@ -774,7 +748,7 @@ class RankingService:
             launch_overhead_trees=self.launch_overhead_trees,
             query_exit=self.query_exit,
         )
-        return mode, engine_config, extra
+        return engine_config, extra
 
 
 def _scope(

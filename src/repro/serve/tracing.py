@@ -11,7 +11,7 @@ batcher worker opens them, one thread, nested as::
         lear.put                    host→device puts of the block and mask
         lear.step                   RankingService.rank_batch
             lear.step.dispatch      placement, engine config, compiled step
-            lear.step.post          eager ops after the step, up to the read
+            lear.step.post          what is left before the read: unpacking
             lear.step.read          the one jax.device_get
             lear.step.adapt         survivor peaks, EMA and stats
         lear.scatter                per-request top-k and set_result

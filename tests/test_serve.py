@@ -6,13 +6,22 @@ strategies (continue ⇔ ``features[..., 0] > 0``) so survivor counts are
 controlled exactly per batch without training a classifier.
 """
 
+import dataclasses
+import logging
+import re
+from contextlib import contextmanager
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.lear import LearClassifier
+from repro.core.strategies import QueryExitConfig
 from repro.forest.ensemble import random_ensemble
+from repro.metrics.speedup import trees_traversed_progressive
 from repro.serve.ranking_service import RankingService, ServiceConfig
+from strategy_harness import make_dense_stage
 
 
 def _service(seed=0, n_trees=64, sentinels=(8, 28), **knobs):
@@ -223,3 +232,146 @@ def test_modes_serve_identical_scores():
         assert svc.stats.overflow_docs == 0
     np.testing.assert_array_equal(out["fused"][0], out["staged"][0])
     np.testing.assert_array_equal(out["fused"][1], out["staged"][1])
+
+
+@contextmanager
+def _compiled_programs():
+    """Names of the programs jax compiles in the block (``jit(step)``, …),
+    from :func:`jax.log_compiles`."""
+    names = []
+    pattern = re.compile(r"Compiling (\S+) ")
+
+    class Handler(logging.Handler):
+        def emit(self, record):
+            m = pattern.match(record.getMessage())
+            if m:
+                names.append(m.group(1))
+
+    handler = Handler(level=logging.DEBUG)
+    logger = logging.getLogger("jax")
+    logger.addHandler(handler)
+    try:
+        with jax.log_compiles():
+            yield names
+    finally:
+        logger.removeHandler(handler)
+
+
+def test_engine_call_is_one_program():
+    """One engine call compiles, and so dispatches, one program: the
+    response and stats are computed inside the step, so a new batch shape
+    after a warm bucket compiles the step alone and no eager op after it;
+    the same shape again compiles nothing."""
+    rng = np.random.default_rng(9)
+    svc = _service(execution_mode="auto", launch_overhead_trees=512.0)
+    svc.rank_batch(*_batch(rng, 2, 32, 12, survive_frac=0.3))
+    X, mask = _batch(rng, 3, 24, 12, survive_frac=0.3)  # a new (Q, D)
+    with _compiled_programs() as compiled:
+        svc.rank_batch(X, mask)
+    assert compiled == ["jit(step)"], compiled
+    with _compiled_programs() as compiled:
+        svc.rank_batch(X, mask)
+    assert compiled == [], compiled
+
+
+def _eager_post(svc, X, mask):
+    """Oracle: the engine call as ``rank_progressive`` followed by the
+    response and stats computed as separate eager ops, read once. The
+    accounting charges a dense gate as a zero-sentinel stage."""
+    Q, D, _ = X.shape
+    sentinels = svc.sentinels
+    costs = [float(c.n_trees) for c in svc.stage_classifiers]
+    if svc.dense_stage is not None:
+        sentinels = (0, *sentinels)
+        costs = [float(svc.dense_stage.cost_trees), *costs]
+    config, extra = svc._engine_call(Q, D)
+    res = svc.cascade.rank_progressive(X, mask, config, features=X, **extra)
+    masked = jnp.where(mask, res.scores, -jnp.inf)
+    picked = (
+        res.picked_staged if res.picked_staged is not None
+        else config.mode == "staged"
+    )
+    q_exited = (
+        res.query_exited.sum() if res.query_exited is not None
+        else jnp.int32(0)
+    )
+    return jax.device_get((
+        jax.lax.top_k(masked, min(svc.top_k, D))[1],
+        res.scores,
+        jnp.stack([m.sum() for m in res.stage_masks]),
+        trees_traversed_progressive(
+            mask, res.stage_masks, sentinels, svc.ensemble.n_trees, costs
+        ),
+        res.overflow,
+        mask.sum(),
+        picked,
+        q_exited,
+    ))
+
+
+@pytest.mark.parametrize("dense", [False, True], ids=["trees", "dense"])
+@pytest.mark.parametrize("query_exit", [False, True], ids=["no_qe", "qe"])
+@pytest.mark.parametrize("mode", ["fused", "staged", "auto"])
+def test_folded_step_matches_eager_post(mode, query_exit, dense):
+    """rank_batch's top-k, scores and every ServiceStats counter it fills
+    are bit-identical to the step followed by the eager post-step ops, over
+    a cold batch that overflows, a sparse one with a query left without
+    survivors and, in auto mode, a batch steered to each branch."""
+    rng = np.random.default_rng(10)
+    Q, D, F = 4, 32, 12
+    knobs = dict(
+        execution_mode=mode, launch_overhead_trees=512.0, survivor_ema=1.0,
+        top_k=5,
+    )
+    if query_exit:
+        knobs["query_exit"] = QueryExitConfig(k=3)
+    if dense:
+        # A fractional dense cost makes the accounting sum non-integer.
+        knobs["dense_stage"] = dataclasses.replace(
+            make_dense_stage(F, seed=11, keep_frac=0.75), cost_trees=0.37
+        )
+    svc = _service(**knobs)
+    busy = _batch(rng, Q, D, F, survive_frac=0.8)
+    X, mask = _batch(rng, Q, D, F, survive_frac=0.2)
+    X = X.at[0, :, 0].set(-1.0)           # query 0: no survivors
+    mask = mask.at[1, D // 2:].set(False)  # query 1: padded half
+    sparse = (X, mask)
+    batches = [(busy, None), (sparse, None)]
+    if mode == "auto":
+        n = svc.n_stages
+        batches += [(sparse, [0.0] * n), (sparse, [float(Q * D)] * n)]
+
+    fields = [f.name for f in dataclasses.fields(svc.stats)
+              if not f.name.endswith("_s")]
+    for (Xb, mb), ema in batches:
+        if ema is not None:
+            svc.bucket_state(Q, D).ema = ema
+        (top_ref, scores_ref, survivors, traversed, overflow, docs, picked,
+         q_exited) = _eager_post(svc, Xb, mb)
+        before = dataclasses.asdict(svc.stats)
+        top_idx, scores = svc.rank_batch(Xb, mb)
+        after = dataclasses.asdict(svc.stats)
+
+        np.testing.assert_array_equal(top_idx, top_ref)
+        np.testing.assert_array_equal(scores, scores_ref)
+        assert svc.bucket_state(Q, D).ema == [float(n) for n in survivors]
+        want = dict(before)
+        want["batches"] += 1
+        want["batches_staged"] += bool(picked)
+        want["batches_fused"] += not bool(picked)
+        want["queries"] += Q
+        want["docs"] += int(docs)
+        want["docs_continued"] += int(survivors[-1])
+        want["overflow_docs"] += int(overflow)
+        want["queries_exited"] += int(q_exited)
+        want["trees_traversed"] += float(traversed)
+        want["trees_full_equiv"] += int(docs) * svc.ensemble.n_trees
+        assert {k: after[k] for k in fields if k != "tail_rows"} == {
+            k: want[k] for k in fields if k != "tail_rows"
+        }
+    s = svc.stats
+    assert s.overflow_docs > 0                 # the cold batch overflowed
+    if query_exit:
+        assert s.queries_exited > 0
+    if mode == "auto":
+        assert s.batches_staged > 0 and s.batches_fused > 0

@@ -208,6 +208,7 @@ def test_single_device_placement_is_identity_and_local_mesh_bitexact():
 
 
 _MULTIDEV_PROG = r"""
+import dataclasses
 import numpy as np
 import jax, jax.numpy as jnp
 assert jax.device_count() == 8, jax.device_count()
@@ -248,13 +249,32 @@ top_r, sc_r = service().rank_batch(*single_device().put(X, mask))
 np.testing.assert_allclose(np.asarray(sc_s), np.asarray(sc_r),
                            rtol=1e-6, atol=1e-6)
 np.testing.assert_array_equal(np.asarray(top_s), np.asarray(top_r))
+
+# data_parallel(4): the folded step (response and stats computed inside the
+# one program, under the mesh) is bit-exact with one device, stats included
+# (tail_rows counts each device's launch padding, so it differs).
+def stats(svc):
+    return {k: v for k, v in dataclasses.asdict(svc.stats).items()
+            if not k.endswith("_s") and k != "tail_rows"}
+
+pl4 = data_parallel(4)
+svc4, svc1 = service(), service()
+X4, m4 = pl4.put(X, mask)
+assert len(X4.sharding.device_set) == 4, X4.sharding
+top4, sc4 = svc4.rank_batch(X4, m4, placement=pl4)
+top1, sc1 = svc1.rank_batch(X, mask)
+np.testing.assert_array_equal(np.asarray(sc4), np.asarray(sc1))
+np.testing.assert_array_equal(np.asarray(top4), np.asarray(top1))
+assert stats(svc4) == stats(svc1), (stats(svc4), stats(svc1))
+assert svc1.stats.docs_continued > 0
 print("MULTIDEV_OK")
 """
 
 
 def test_data_parallel_placement_8_devices():
     """The sharded serving path on a forced 8-device CPU: query axis split
-    across the mesh, results matching the single-device reference."""
+    across the mesh, results matching the single-device reference, and on
+    a ``data_parallel(4)`` mesh bit-exact with it, stats included."""
     res = subprocess.run(
         [sys.executable, "-c", _MULTIDEV_PROG],
         capture_output=True, text=True, timeout=570,
