@@ -7,17 +7,22 @@ shaped ``[T, n_nodes]`` / ``[T, n_leaves]``. Two traversal encodings coexist:
 1. **Structural** (``left``/``right`` child indices) for classic root→leaf
    level stepping. Child entries ``>= 0`` index internal nodes; entries
    ``< 0`` encode leaves as ``-(leaf_id + 1)``.
-2. **QuickScorer bitmask** (``mask_lo``/``mask_hi``): for each internal node
-   ``n``, a 64-bit mask (two uint32 lanes) with zeros at the leaves of the
-   *left* subtree of ``n``. QuickScorer's theorem: the exit leaf of a
-   document is the **lowest set bit** of the AND of the masks of its *false*
-   nodes (nodes whose test ``x[feat] <= thr`` fails). True/padded nodes
-   contribute the all-ones mask, making the reduction order-free — the key
-   property that maps the CPU algorithm onto TPU vector units.
+2. **QuickScorer bitmask** (``mask``): for each internal node ``n``, an
+   ``n_leaves``-bit mask with zeros at the leaves of the *left* subtree of
+   ``n``, stored as ``W = ceil(n_leaves / 32)`` uint32 words (word ``w``
+   holds leaves ``[32w, 32w + 32)``; :func:`mask_words`). QuickScorer's
+   theorem: the exit leaf of a document is the **lowest set bit** of the
+   AND of the masks of its *false* nodes (nodes whose test
+   ``x[feat] <= thr`` fails). True/padded nodes contribute the all-ones
+   mask, making the reduction order-free — the key property that maps the
+   CPU algorithm onto TPU vector units.
 
 Leaves are numbered left-to-right (in-order), which is what makes the
-lowest-set-bit rule correct. ``n_leaves`` must be ≤ 64 for the bitmask
-encoding (the paper's trees have ≤ 64 leaves).
+lowest-set-bit rule correct. The word count follows from ``n_leaves``
+alone: 1 word up to 32 leaves, 2 for the paper's 64-leaf trees, 8 for the
+256-leaf trees of a depth-8 λ-MART. Both builders refuse trees of more
+than :data:`MAX_LEAVES` leaves, the widest the forest kernel's tiling
+covers (:mod:`repro.kernels.forest_score`).
 """
 
 from __future__ import annotations
@@ -30,6 +35,25 @@ import jax.numpy as jnp
 import numpy as np
 
 ALL_ONES = np.uint32(0xFFFFFFFF)
+
+#: Leaves per mask word.
+WORD_BITS = 32
+#: The widest tree either builder accepts: the forest kernel derives its
+#: tree block from the padded tree shape down to 4 trees of 256 leaves.
+MAX_LEAVES = 256
+
+
+def mask_words(n_leaves: int) -> int:
+    """uint32 words of a node's false-leaf mask for ``n_leaves`` leaves."""
+    return max(1, -(-int(n_leaves) // WORD_BITS))
+
+
+def _check_leaves(n_leaves: int) -> None:
+    if n_leaves > MAX_LEAVES:
+        raise ValueError(
+            f"trees of {n_leaves} leaves: the bitmask encoding and the "
+            f"forest kernel take at most {MAX_LEAVES}"
+        )
 
 
 @jax.tree_util.register_dataclass
@@ -50,8 +74,7 @@ class TreeEnsemble:
     threshold: jax.Array  # [T, N] float32 — split threshold (x <= thr → left)
     left: jax.Array       # [T, N] int32 — left child (neg = ~leaf encoding)
     right: jax.Array      # [T, N] int32
-    mask_lo: jax.Array    # [T, N] uint32 — QS false-node mask, low lane
-    mask_hi: jax.Array    # [T, N] uint32 — high lane
+    mask: jax.Array       # [T, N, W] uint32 — QS false-node mask, W words
     leaf_value: jax.Array  # [T, L] float32
     base_score: jax.Array  # [] float32 — additive offset (e.g. logit prior)
 
@@ -89,8 +112,7 @@ def slice_trees(ens: TreeEnsemble, start: int, stop: int) -> TreeEnsemble:
         threshold=ens.threshold[start:stop],
         left=ens.left[start:stop],
         right=ens.right[start:stop],
-        mask_lo=ens.mask_lo[start:stop],
-        mask_hi=ens.mask_hi[start:stop],
+        mask=ens.mask[start:stop],
         leaf_value=ens.leaf_value[start:stop],
         base_score=keep_base,
     )
@@ -103,8 +125,7 @@ def concat_ensembles(parts: Sequence[TreeEnsemble]) -> TreeEnsemble:
         threshold=jnp.concatenate([p.threshold for p in parts]),
         left=jnp.concatenate([p.left for p in parts]),
         right=jnp.concatenate([p.right for p in parts]),
-        mask_lo=jnp.concatenate([p.mask_lo for p in parts]),
-        mask_hi=jnp.concatenate([p.mask_hi for p in parts]),
+        mask=jnp.concatenate([p.mask for p in parts]),
         leaf_value=jnp.concatenate([p.leaf_value for p in parts]),
         base_score=base,
     )
@@ -135,11 +156,16 @@ def _leaf_spans(left: np.ndarray, right: np.ndarray, n_nodes: int):
     return spans, counter[0]
 
 
-def _span_mask(lo: int, hi: int) -> tuple[np.uint32, np.uint32]:
-    """64-bit mask with zeros on bits [lo, hi), split into two uint32 lanes."""
+def _span_mask(lo: int, hi: int, words: int) -> np.ndarray:
+    """``[words]`` uint32: a ``32·words``-bit mask with zeros on bits
+    [lo, hi), word ``w`` holding bits [32w, 32w + 32)."""
+    assert hi <= words * WORD_BITS, (hi, words)
     bits = ((1 << hi) - 1) ^ ((1 << lo) - 1)  # ones on [lo, hi)
-    inv = (~bits) & ((1 << 64) - 1)
-    return np.uint32(inv & 0xFFFFFFFF), np.uint32(inv >> 32)
+    inv = ~bits & ((1 << (words * WORD_BITS)) - 1)
+    return np.array(
+        [(inv >> (WORD_BITS * w)) & 0xFFFFFFFF for w in range(words)],
+        dtype=np.uint32,
+    )
 
 
 def from_arrays(
@@ -164,15 +190,14 @@ def from_arrays(
     n_nodes = n_nodes or max_int
     max_leaves = max(int(lv.shape[0]) for lv in leaf_values)
     n_leaves = n_leaves or max_leaves
-    if n_leaves > 64:
-        raise ValueError(f"bitmask encoding requires <=64 leaves, got {n_leaves}")
+    _check_leaves(n_leaves)
+    words = mask_words(n_leaves)
 
     feat = np.zeros((T, n_nodes), dtype=np.int32)
     thr = np.full((T, n_nodes), np.float32(np.inf))  # padded → always-true node
     left = np.full((T, n_nodes), -1, dtype=np.int32)
     right = np.full((T, n_nodes), -1, dtype=np.int32)
-    mlo = np.full((T, n_nodes), ALL_ONES, dtype=np.uint32)
-    mhi = np.full((T, n_nodes), ALL_ONES, dtype=np.uint32)
+    mask = np.full((T, n_nodes, words), ALL_ONES, dtype=np.uint32)
     lv = np.zeros((T, n_leaves), dtype=np.float32)
 
     for t in range(T):
@@ -203,15 +228,14 @@ def from_arrays(
                 c = int(arr_in[n])
                 arr_out[t, n] = c if c >= 0 else -(int(old2new[-(c + 1)]) + 1)
             lo, mid, _hi = spans[n]
-            mlo[t, n], mhi[t, n] = _span_mask(int(lo), int(mid))
+            mask[t, n] = _span_mask(int(lo), int(mid), words)
 
     return TreeEnsemble(
         feature=jnp.asarray(feat),
         threshold=jnp.asarray(thr),
         left=jnp.asarray(left),
         right=jnp.asarray(right),
-        mask_lo=jnp.asarray(mlo),
-        mask_hi=jnp.asarray(mhi),
+        mask=jnp.asarray(mask),
         leaf_value=jnp.asarray(lv),
         base_score=jnp.float32(base_score),
     )
@@ -231,10 +255,11 @@ def from_complete_arrays(
     T, n_int = feature.shape
     depth = int(np.log2(n_int + 1))
     n_leaves = 1 << depth
+    _check_leaves(n_leaves)
+    words = mask_words(n_leaves)
     left = np.zeros((T, n_int), dtype=np.int32)
     right = np.zeros((T, n_int), dtype=np.int32)
-    mlo = np.zeros((T, n_int), dtype=np.uint32)
-    mhi = np.zeros((T, n_int), dtype=np.uint32)
+    mask = np.zeros((T, n_int, words), dtype=np.uint32)
     for n in range(n_int):
         d = int(np.floor(np.log2(n + 1)))
         # Heap node n is the (n - (2**d - 1))-th node of level d; its subtree
@@ -246,15 +271,13 @@ def from_complete_arrays(
         l_child, r_child = 2 * n + 1, 2 * n + 2
         left[:, n] = l_child if l_child < n_int else -(lo + 1)
         right[:, n] = r_child if r_child < n_int else -(mid + 1)
-        a, b = _span_mask(lo, mid)
-        mlo[:, n], mhi[:, n] = a, b
+        mask[:, n] = _span_mask(lo, mid, words)
     return TreeEnsemble(
         feature=jnp.asarray(feature.astype(np.int32)),
         threshold=jnp.asarray(threshold.astype(np.float32)),
         left=jnp.asarray(left),
         right=jnp.asarray(right),
-        mask_lo=jnp.asarray(mlo),
-        mask_hi=jnp.asarray(mhi),
+        mask=jnp.asarray(mask),
         leaf_value=jnp.asarray(leaf_value.astype(np.float32)),
         base_score=jnp.float32(base_score),
     )

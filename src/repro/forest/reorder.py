@@ -150,8 +150,7 @@ def reorder_trees(ens: TreeEnsemble, order: np.ndarray) -> TreeEnsemble:
         threshold=jnp.take(ens.threshold, take, axis=0),
         left=jnp.take(ens.left, take, axis=0),
         right=jnp.take(ens.right, take, axis=0),
-        mask_lo=jnp.take(ens.mask_lo, take, axis=0),
-        mask_hi=jnp.take(ens.mask_hi, take, axis=0),
+        mask=jnp.take(ens.mask, take, axis=0),
         leaf_value=jnp.take(ens.leaf_value, take, axis=0),
         base_score=ens.base_score,
     )

@@ -24,16 +24,23 @@ import numpy as np
 from repro.forest.ensemble import TreeEnsemble
 
 
-def _ctz64(hi: jax.Array, lo: jax.Array) -> jax.Array:
-    """Count trailing zeros of a 64-bit value in two uint32 lanes.
+def _ctz_words(words: jax.Array) -> jax.Array:
+    """Count trailing zeros of a multi-word value, ``words[..., w]`` holding
+    bits ``[32w, 32w + 32)`` → int32 ``[...]``.
 
-    ctz(m) = popcount(~m & (m - 1)); the AND of QS masks is never 0 (the
-    exit leaf bit always survives), so no special case is needed.
+    ctz(m) = popcount(~m & (m - 1)) in the lowest nonzero word; the AND of
+    QS masks is never 0 (the exit leaf bit always survives), so no special
+    case is needed.
     """
-    lo_nz = lo != 0
-    m = jnp.where(lo_nz, lo, hi)
-    ctz32 = jax.lax.population_count(~m & (m - jnp.uint32(1)))
-    return jnp.where(lo_nz, ctz32, ctz32 + jnp.uint32(32)).astype(jnp.int32)
+    n_words = words.shape[-1]
+    leaf = None
+    for w in reversed(range(n_words)):
+        m = words[..., w]
+        ctz = jax.lax.population_count(~m & (m - jnp.uint32(1))).astype(
+            jnp.int32
+        ) + jnp.int32(32 * w)
+        leaf = ctz if leaf is None else jnp.where(m != 0, ctz, leaf)
+    return leaf
 
 
 def exit_leaves_bitvector(ens: TreeEnsemble, X: jax.Array) -> jax.Array:
@@ -42,12 +49,10 @@ def exit_leaves_bitvector(ens: TreeEnsemble, X: jax.Array) -> jax.Array:
     xf = X[:, ens.feature]  # fancy-index over axis 1 with [T, N] indices
     pred_true = xf <= ens.threshold[None, :, :]
     ones = jnp.uint32(0xFFFFFFFF)
-    m_lo = jnp.where(pred_true, ones, ens.mask_lo[None, :, :])
-    m_hi = jnp.where(pred_true, ones, ens.mask_hi[None, :, :])
-    # Order-free AND-reduction over the node axis.
-    and_lo = jax.lax.reduce(m_lo, ones, jax.lax.bitwise_and, dimensions=(2,))
-    and_hi = jax.lax.reduce(m_hi, ones, jax.lax.bitwise_and, dimensions=(2,))
-    return _ctz64(and_hi, and_lo)
+    m = jnp.where(pred_true[..., None], ones, ens.mask[None])  # [B, T, N, W]
+    # Order-free AND-reduction over the node axis, per word.
+    words = jax.lax.reduce(m, ones, jax.lax.bitwise_and, dimensions=(2,))
+    return _ctz_words(words)
 
 
 def score_bitvector(

@@ -7,21 +7,29 @@ sequential (minor) accumulation axis. Per grid step, VMEM holds:
 
 - one doc block      ``x        [BB, F]``     (f32, F lane-padded)
 - one tree block     ``feature  [1, N·BT]`` / ``threshold [1, N·BT]``
--                    ``mask_lo/hi [1, N·BT]`` (u32, QuickScorer false-node masks)
+-                    ``mask     [1, W·N·BT]`` (u32, QuickScorer false-node
+  masks, W words per node, word-major)
 -                    ``leaf_value [1, L·BT]`` (f32)
 - the output block   ``scores   [BB, S]``     (f32, accumulated across tree
   blocks; S = 1 for the plain kernel)
+
+The tree block ``BT`` follows from the padded tree shape
+(:func:`tree_block`): ``max(N, L)·BT`` stays within :data:`ROW_LANES`, so
+16 trees of up to 64 leaves, 8 of 128 and 4 of 256. Every per-step
+intermediate is then ``[BB, ≤ 1024]`` whatever the depth, and ``BB`` stays
+the engine's 256-row doc block (:data:`repro.kernels.ops.ENGINE_BLOCK_B`).
 
 Lane layout
 -----------
 Every tree table enters the kernel as ONE lane-dense row per tree block,
 node-major: lane ``n·BT + t`` holds node ``n`` of tree ``t`` (leaf tables:
-lane ``l·BT + t``). The entry points below build these rows from the
-``[T, N]`` / ``[T, L]`` tables (a transpose per tree block), padding the
-node and leaf axes so a row spans whole 128-lane vregs. With that layout
-every in-kernel step is either a slice at a 128-lane boundary, a sublane
-broadcast of a row, or a lane rotation (``pltpu.roll``) — the shapes
-Mosaic compiles — and no value is ever reshaped across the lane axis.
+lane ``l·BT + t``; mask word ``w`` at lane ``w·N·BT + n·BT + t``). The
+entry points below build these rows from the ``[T, N]`` / ``[T, N, W]`` /
+``[T, L]`` tables (a transpose per tree block), padding the node and leaf
+axes so a row spans whole 128-lane vregs. With that layout every in-kernel
+step is either a slice at a 128-lane boundary, a sublane broadcast of a
+row, or a lane rotation (``pltpu.roll``) — the shapes Mosaic compiles —
+and no value is ever reshaped across the lane axis.
 
 Algorithm (per doc block × tree block)
 --------------------------------------
@@ -31,15 +39,21 @@ Algorithm (per doc block × tree block)
    parts (``hi + mid + lo == x`` exactly) and each part multiplies the
    0/1 one-hot in bf16 with f32 accumulation, so every output is a single
    exact product — tree routing never depends on the MXU's f32 precision.
-2. Node predicates ``x_f <= θ`` select either the all-ones word or the
-   node's false-mask (two u32 lanes).
-3. Order-free AND-reduction over the node axis: halving at 128-lane
-   boundaries down to one vreg, then a rotate-and-AND butterfly over the
-   node groups inside it. Every lane ``j`` ends up holding the AND for
-   tree ``j mod BT``.
-4. Exit leaf = count-trailing-zeros via ``popcount(~m & (m−1))`` on the two
-   lanes, then leaf values are resolved through one of three *leaf-gather
-   paths* (see below).
+2. Node predicates ``x_f <= θ`` select, per mask word, either the
+   all-ones word or the node's false-leaf word.
+3. Order-free AND-reduction over the nodes, word by word: each word ANDs
+   the 128-lane node columns ``word_columns[w]`` names, then a
+   rotate-and-AND butterfly over the node groups inside the vreg. Every
+   lane ``j`` ends up holding the AND for tree ``j mod BT``. A column left
+   out of a word holds no node that zeroes it (its words are all ones), so
+   leaving it out moves no bit. :func:`repro.kernels.ops.padded_forest`
+   orders each tree's nodes by the highest word they zero, so a word of a
+   256-leaf tree reads 1–3 of the 8 columns instead of all of them.
+4. **Exit-leaf rule** — QuickScorer's: the exit leaf is the lowest set
+   bit of the AND, found as count-trailing-zeros via
+   ``popcount(~m & (m−1))`` of the lowest nonzero word plus its ``32·w``
+   offset. Leaf values are then resolved through one of three
+   *leaf-gather paths* (see below).
 5. The BT per-tree values are summed by a pairwise rotate-and-add
    (:func:`_pairwise_lane_sum`, the association of
    :func:`_pairwise_tree_sum`), and the tree-block partial accumulates into
@@ -51,7 +65,8 @@ Three interchangeable paths resolve ``leaf_value[t, leaf[b, t]]``,
 selected by the static ``leaf_gather`` argument; all move the SAME f32
 values, so they are bit-exact with each other:
 
-- ``"select"`` (default for L ≤ :data:`repro.kernels.ops.LEAF_SELECT_MAX`): a
+- ``"select"`` (default for L ≤ :data:`repro.kernels.ops.LEAF_SELECT_MAX`,
+  256, every tree the builders take): a
   select tree over the bits of the ctz leaf index, MSB first. The high
   bits pick 128-lane blocks of the leaf row (the first round reads the
   row directly); the low bits pick a leaf group inside the last vreg by
@@ -93,10 +108,14 @@ its partial into that segment's column through a lane-iota mask
 score of every document at EVERY sentinel; prefix scores are a ``[B, S]``
 cumsum outside the kernel. The plain kernel is the same body with S = 1.
 
-VMEM budget (defaults ``BB=256, BT=16, N=63→64, L=64, F≤256``): x 256 KiB
-(double-buffered 512 KiB); one-hot ``[F, N·BT]`` bf16 512 KiB; gathered
-features and the two mask words ``[BB, N·BT]`` 1 MiB each; tree rows
-4 KiB each — a few MiB, inside the default scoped-VMEM limit.
+VMEM budget. ``BB = 256``, ``F ≤ 256`` and ``max(N, L)·BT = 1024`` at
+every depth: 16 trees of 64 leaves (``N = 63→64``, 2 mask words) or 4
+trees of 256 (``N = 255→256``, 8 words). x 256 KiB (double-buffered 512
+KiB); one-hot ``[F, 1024]`` bf16 512 KiB; gathered features and the
+predicates ``[BB, 1024]`` 1 MiB each; one mask word ``[BB, 128]`` column
+128 KiB at a time; tree rows 4 KiB each for features, thresholds and
+leaves and ``W × 4`` KiB for the mask (32 KiB at 256 leaves), double
+buffered — a few MiB, inside the default scoped-VMEM limit.
 """
 
 from __future__ import annotations
@@ -116,6 +135,20 @@ ALL_ONES = np.uint32(0xFFFFFFFF)
 LEAF_GATHERS = ("onehot", "select", "mxu")
 
 LANE = 128
+
+#: Lanes of a tree block's widest table row (``max(N, L)·BT``): the paper's
+#: depth-6 trees at ``BT = 16`` (64 × 16). Every per-block intermediate is
+#: ``[BB, ≤ ROW_LANES]``, whatever the tree width, so the VMEM budget of
+#: the module docstring holds at any depth up to 256 leaves.
+ROW_LANES = 1024
+
+
+def tree_block(requested: int, n_nodes: int, n_leaves: int) -> int:
+    """Trees per block for a padded tree shape: ``requested`` (a power of
+    two), capped so that ``max(N, L)·BT`` stays within :data:`ROW_LANES` —
+    16 up to 64 leaves, 8 at 128, 4 at 256."""
+    widest = _next_pow2(max(n_nodes, n_leaves))
+    return max(1, min(requested, ROW_LANES // widest))
 
 
 def _next_pow2(n: int) -> int:
@@ -153,21 +186,41 @@ def _exact_dot(a: jax.Array, sel: jax.Array) -> jax.Array:
     return (dot(hi) + dot(mid)) + dot(lo)
 
 
-def _ctz64(hi: jax.Array, lo: jax.Array) -> jax.Array:
-    lo_nz = lo != 0
-    m = jnp.where(lo_nz, lo, hi)
-    ctz32 = jax.lax.population_count(~m & (m - jnp.uint32(1)))
-    return jnp.where(lo_nz, ctz32, ctz32 + jnp.uint32(32)).astype(jnp.int32)
+def _ctz_words(words: list[jax.Array]) -> jax.Array:
+    """Count trailing zeros of a ``32·W``-bit value held as W uint32
+    planes (``words[w]``: bits ``[32w, 32w + 32)``): ``popcount(~m & (m−1))``
+    of the lowest nonzero word, plus its bit offset. The AND of QuickScorer
+    masks always keeps the exit leaf's bit, so some word is nonzero."""
+    leaf = None
+    for w in reversed(range(len(words))):
+        m = words[w]
+        ctz = jax.lax.population_count(~m & (m - jnp.uint32(1))).astype(
+            jnp.int32
+        ) + jnp.int32(32 * w)
+        leaf = ctz if leaf is None else jnp.where(m != 0, ctz, leaf)
+    return leaf
 
 
-def _and_over_nodes(m: jax.Array, block_t: int) -> jax.Array:
-    """AND over the node axis of a node-major ``[BB, N·BT]`` mask row →
-    ``[BB, 128]`` with lane ``j`` holding tree ``j mod BT``. AND is
-    associative and commutative, so the order is free."""
-    w = m.shape[1]
-    while w > LANE:
-        w //= 2
-        m = m[:, :w] & m[:, w:]
+def _and_over_columns(
+    pred_true: jax.Array, mask_ref: Ref, offset: int,
+    columns: tuple[int, ...], block_t: int,
+) -> jax.Array:
+    """One mask word's AND over the nodes of the 128-lane ``columns`` of a
+    node-major row → ``[BB, 128]`` with lane ``j`` holding tree
+    ``j mod BT``; the word's row starts at lane ``offset`` of the mask row.
+    A column left out holds no node that zeroes this word, so its words
+    are all ones. AND is associative and commutative, so the order is
+    free."""
+    if not columns:
+        return jnp.full((pred_true.shape[0], LANE), ALL_ONES)
+    m = None
+    for c in columns:
+        lanes = slice(c * LANE, (c + 1) * LANE)
+        word = jnp.where(
+            pred_true[:, lanes], ALL_ONES,
+            mask_ref[:, offset + c * LANE:offset + (c + 1) * LANE],
+        )
+        m = word if m is None else m & word
     s = LANE // 2
     while s >= block_t:
         m = m & _lane_read(m, s)
@@ -301,32 +354,34 @@ def _pairwise_lane_sum(per_tree: jax.Array, block_t: int) -> jax.Array:
 
 
 def _score_block(
-    x_ref: Ref, feat_ref: Ref, thr_ref: Ref, mlo_ref: Ref, mhi_ref: Ref,
-    leaf_ref: Ref,
+    x_ref: Ref, feat_ref: Ref, thr_ref: Ref, mask_ref: Ref, leaf_ref: Ref,
     *,
     block_t: int,
+    word_columns: tuple[tuple[int, ...], ...],
     leaf_gather: str,
 ) -> jax.Array:
     """One doc-block × tree-block partial score ``[BB, 1]`` (steps 1-5)."""
     x = x_ref[...]
-    feat = feat_ref[...]                                         # [1, W]
+    feat = feat_ref[...]                                         # [1, N·BT]
     F = x.shape[1]
-    W = feat.shape[1]
+    width = feat.shape[1]
 
     # (1) Feature gather: xf[b, n·BT + t] = x[b, feature[t, n]].
-    feat_iota = jax.lax.broadcasted_iota(jnp.int32, (F, W), 0)
+    feat_iota = jax.lax.broadcasted_iota(jnp.int32, (F, width), 0)
     onehot = jnp.where(feat_iota == feat, 1.0, 0.0).astype(jnp.bfloat16)
-    xf = _exact_dot(x, onehot)                                   # [BB, W]
+    xf = _exact_dot(x, onehot)                                   # [BB, N·BT]
 
-    # (2) Predicates → mask selection.
+    # (2) Predicates select, per mask word, the all-ones word or the
+    # node's false-leaf word, and (3) the selected words are ANDed over the
+    # word's node columns, one [BB, 128] column at a time.
     pred_true = xf <= thr_ref[...]
-    m_lo = jnp.where(pred_true, ALL_ONES, mlo_ref[...])
-    m_hi = jnp.where(pred_true, ALL_ONES, mhi_ref[...])
+    words = [
+        _and_over_columns(pred_true, mask_ref, w * width, columns, block_t)
+        for w, columns in enumerate(word_columns)
+    ]
 
-    # (3) AND over nodes → (4) exit leaf → leaf value, lane j = tree j % BT.
-    leaf = _ctz64(
-        _and_over_nodes(m_hi, block_t), _and_over_nodes(m_lo, block_t)
-    )                                                            # [BB, 128]
+    # (4) Exit leaf → leaf value, lane j = tree j % BT.
+    leaf = _ctz_words(words)                                     # [BB, 128]
     per_tree = _LEAF_VALUE_FNS[leaf_gather](leaf, leaf_ref, block_t)
     return _pairwise_lane_sum(per_tree, block_t)                 # [BB, 1]
 
@@ -335,18 +390,18 @@ def _forest_kernel(
     x_ref: Ref,        # [BB, F] f32
     feat_ref: Ref,     # [1, N·BT] i32
     thr_ref: Ref,      # [1, N·BT] f32
-    mlo_ref: Ref,      # [1, N·BT] u32
-    mhi_ref: Ref,      # [1, N·BT] u32
+    mask_ref: Ref,     # [1, W·N·BT] u32, word-major
     leaf_ref: Ref,     # [1, L·BT] f32
     out_ref: Ref,      # [BB, S] f32 — per-segment partials, accumulated over j
     *,
     seg_block_starts: tuple[int, ...],
     block_t: int,
+    word_columns: tuple[tuple[int, ...], ...],
     leaf_gather: str,
 ) -> None:
     partial = _score_block(
-        x_ref, feat_ref, thr_ref, mlo_ref, mhi_ref, leaf_ref,
-        block_t=block_t, leaf_gather=leaf_gather,
+        x_ref, feat_ref, thr_ref, mask_ref, leaf_ref,
+        block_t=block_t, word_columns=word_columns, leaf_gather=leaf_gather,
     )
 
     # Segment id of this tree block: static unrolled predicate sum (scalar).
@@ -391,8 +446,7 @@ def _forest_call(
     x: jax.Array,
     feature: jax.Array,
     threshold: jax.Array,
-    mask_lo: jax.Array,
-    mask_hi: jax.Array,
+    mask: jax.Array,
     leaf_value: jax.Array,
     *,
     block_b: int,
@@ -400,6 +454,7 @@ def _forest_call(
     tree_block_offset: int,
     n_tree_blocks: int,
     seg_block_starts: tuple[int, ...],
+    word_columns: tuple[tuple[int, ...], ...] | None,
     leaf_gather: str,
     interpret: bool,
 ) -> jax.Array:
@@ -407,6 +462,7 @@ def _forest_call(
     B, F = x.shape
     T, N = feature.shape
     L = leaf_value.shape[1]
+    assert mask.shape[:2] == (T, N), (mask.shape, T, N)
     assert B % block_b == 0 and T % block_t == 0, (B, block_b, T, block_t)
     assert N & (N - 1) == 0, f"node axis must be a power of two, got {N}"
     assert block_t & (block_t - 1) == 0 and block_t <= LANE, block_t
@@ -421,22 +477,36 @@ def _forest_call(
     per_vreg = LANE // block_t
     n_width = max(N, per_vreg)
     l_width = -(-L // per_vreg) * per_vreg
+    # The mask row is word-major: word w of node n of tree t at lane
+    # w·n_width·BT + n·BT + t, so each word is a 128-aligned slice.
+    mask_row = jnp.concatenate(
+        [
+            _tree_rows(mask[..., w], block_t, n_width, ALL_ONES)
+            for w in range(mask.shape[2])
+        ],
+        axis=2,
+    )
     rows = [
         _tree_rows(feature, block_t, n_width, 0),
         _tree_rows(threshold, block_t, n_width, np.inf),
-        _tree_rows(mask_lo, block_t, n_width, ALL_ONES),
-        _tree_rows(mask_hi, block_t, n_width, ALL_ONES),
+        mask_row,
         _tree_rows(leaf_value, block_t, l_width, 0.0),
     ]
     tree_spec = lambda width: pl.BlockSpec(
         (pl.squeezed, 1, width),
         lambda i, j: (j + tree_block_offset, 0, 0),
     )
+    n_columns = n_width * block_t // LANE
+    if word_columns is None:
+        word_columns = (tuple(range(n_columns)),) * mask.shape[2]
+    assert len(word_columns) == mask.shape[2], (word_columns, mask.shape)
+    assert all(0 <= c < n_columns for cs in word_columns for c in cs)
     n_seg = len(seg_block_starts)
     kernel = functools.partial(
         _forest_kernel,
         seg_block_starts=seg_block_starts,
         block_t=block_t,
+        word_columns=word_columns,
         leaf_gather=leaf_gather,
     )
     return pl.pallas_call(
@@ -456,33 +526,34 @@ def _forest_call(
     jax.jit,
     static_argnames=(
         "block_b", "block_t", "tree_block_offset", "n_tree_blocks",
-        "leaf_gather", "interpret",
+        "word_columns", "leaf_gather", "interpret",
     ),
 )
 def forest_score_pallas(
     x: Float32[Array, "b f"],          # B % block_b == 0, F lane-padded
     feature: Int32[Array, "t n"],      # T % block_t == 0, N power of two
     threshold: Float32[Array, "t n"],
-    mask_lo: UInt32[Array, "t n"],
-    mask_hi: UInt32[Array, "t n"],
+    mask: UInt32[Array, "t n w"],
     leaf_value: Float32[Array, "t l"],
     *,
     block_b: int = 256,
     block_t: int = 16,
     tree_block_offset: int = 0,
     n_tree_blocks: int | None = None,
+    word_columns: tuple[tuple[int, ...], ...] | None = None,
     leaf_gather: str = "onehot",
     interpret: bool = True,
 ) -> Float32[Array, "b"]:
     if n_tree_blocks is None:
         n_tree_blocks = feature.shape[0] // block_t - tree_block_offset
     return _forest_call(
-        x, feature, threshold, mask_lo, mask_hi, leaf_value,
+        x, feature, threshold, mask, leaf_value,
         block_b=block_b,
         block_t=block_t,
         tree_block_offset=tree_block_offset,
         n_tree_blocks=n_tree_blocks,
         seg_block_starts=(0,),
+        word_columns=word_columns,
         leaf_gather=leaf_gather,
         interpret=interpret,
     )[:, 0]
@@ -492,21 +563,21 @@ def forest_score_pallas(
     jax.jit,
     static_argnames=(
         "seg_block_starts", "n_tree_blocks", "block_b", "block_t",
-        "leaf_gather", "interpret",
+        "word_columns", "leaf_gather", "interpret",
     ),
 )
 def forest_score_segments_pallas(
     x: Float32[Array, "b f"],          # B % block_b == 0, F lane-padded
     feature: Int32[Array, "t n"],      # T % block_t == 0, N power of two
     threshold: Float32[Array, "t n"],
-    mask_lo: UInt32[Array, "t n"],
-    mask_hi: UInt32[Array, "t n"],
+    mask: UInt32[Array, "t n w"],
     leaf_value: Float32[Array, "t l"],
     *,
     seg_block_starts: tuple[int, ...],  # ascending, seg_block_starts[0] == 0
     n_tree_blocks: int,                 # launch covers blocks [0, n)
     block_b: int = 256,
     block_t: int = 16,
+    word_columns: tuple[tuple[int, ...], ...] | None = None,
     leaf_gather: str = "onehot",
     interpret: bool = True,
 ) -> Float32[Array, "b s"]:
@@ -520,12 +591,13 @@ def forest_score_segments_pallas(
     assert list(seg_block_starts) == sorted(set(seg_block_starts))
     assert seg_block_starts[-1] < n_tree_blocks
     return _forest_call(
-        x, feature, threshold, mask_lo, mask_hi, leaf_value,
+        x, feature, threshold, mask, leaf_value,
         block_b=block_b,
         block_t=block_t,
         tree_block_offset=0,
         n_tree_blocks=n_tree_blocks,
         seg_block_starts=seg_block_starts,
+        word_columns=word_columns,
         leaf_gather=leaf_gather,
         interpret=interpret,
     )

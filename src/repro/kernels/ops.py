@@ -86,6 +86,7 @@ from repro.kernels.forest_score import (
     _next_pow2,
     forest_score_pallas,
     forest_score_segments_pallas,
+    tree_block,
 )
 
 if typing.TYPE_CHECKING:  # annotation-only: keeps ops importable before
@@ -93,6 +94,7 @@ if typing.TYPE_CHECKING:  # annotation-only: keeps ops importable before
 
 LANE = 128
 ALL_ONES = np.uint32(0xFFFFFFFF)
+WORD_BITS = 32   # leaves per mask word (repro.forest.ensemble.WORD_BITS)
 
 #: Mesh axis the kernel launches split their document rows over — the
 #: query axis of :func:`repro.serve.placement.data_parallel`.
@@ -137,12 +139,13 @@ ENGINE_BLOCK_B = 256
 PADDED_CACHE_MAX = env_int("REPRO_PADDED_CACHE_MAX", 8)
 
 # Auto leaf-gather policy cutoff: select tree up to this many (padded)
-# leaves, MXU contraction above. The paper's trees cap at 64 leaves (the
-# bitmask bound), so serving traffic takes the select path; the MXU
-# fallback covers wide synthetic/padded leaf tables. The crossover was
-# measured in interpret mode (ROADMAP item 1 revisits it on real
-# hardware), hence overridable per deployment.
-LEAF_SELECT_MAX = env_int("REPRO_LEAF_SELECT_MAX", 64)
+# leaves, MXU contraction above. Measured on a TPU v5e at the widest tree
+# either builder takes, 256 leaves (tree blocks of 4, a 1,024-lane leaf
+# row as at 64 leaves): the select tree scored lear-msn1-d8's tail launch
+# in 4.37 ms against the MXU contraction's 5.01 ms (2,048 rows, 450
+# trees), so every tree the engine builds takes the select path; the MXU
+# path serves leaf tables wider than that. Overridable per deployment.
+LEAF_SELECT_MAX = env_int("REPRO_LEAF_SELECT_MAX", 256)
 
 
 def resolve_leaf_gather(n_leaves: int) -> str:
@@ -241,14 +244,16 @@ class PaddedForest:
 
     feature: jax.Array     # [T_pad, N_pad] i32
     threshold: jax.Array   # [T_pad, N_pad] f32
-    mask_lo: jax.Array     # [T_pad, N_pad] u32
-    mask_hi: jax.Array     # [T_pad, N_pad] u32
+    mask: jax.Array        # [T_pad, N_pad, W] u32
     leaf_value: jax.Array  # [T_pad, L_layout] f32 — see leaf_layout
     base_score: jax.Array  # [] f32
     boundaries: tuple[int, ...]       # cumulative tree-unit segment ends
     seg_block_starts: tuple[int, ...]  # per-segment start, in blocks
     seg_blocks: tuple[int, ...]        # per-segment length, in blocks
     block_t: int
+    # Per mask word, the 128-lane node columns of a tree-block row that
+    # hold a node zeroing that word in some tree (see _group_by_word).
+    word_columns: tuple[tuple[int, ...], ...]
     leaf_gather: str = "onehot"   # resolved kernel path for this buffer set
     leaf_layout: str = "native"   # "pow2": leaf axis padded for the select
     #   path's contiguous-half bit walk; "native": ensemble leaf axis as-is
@@ -285,7 +290,7 @@ def padded_forest(
     assert boundaries[-1] == T, (boundaries, T)
     assert all(b > 0 for b in boundaries)
     assert list(boundaries) == sorted(set(boundaries)), boundaries
-    block_t = min(block_t, _next_pow2(max(T, 1)))
+    block_t = _tree_block_for(ens, block_t)
     if leaf_gather == "auto":
         leaf_gather = resolve_leaf_gather(ens.n_leaves)
 
@@ -309,6 +314,97 @@ def padded_forest(
         )
 
 
+def _tree_block_for(ens: TreeEnsemble, block_t: int) -> int:
+    """The tree block :func:`padded_forest` lays ``ens`` out in: the
+    requested ``block_t``, capped by the tree count and the padded tree
+    shape (:func:`repro.kernels.forest_score.tree_block`)."""
+    T, N = ens.feature.shape
+    return tree_block(
+        min(block_t, _next_pow2(max(T, 1))),
+        node_slots(N, ens.mask.shape[2]), ens.n_leaves,
+    )
+
+
+def segment_tree_slots(
+    ens: TreeEnsemble, boundaries: tuple[int, ...], block_t: int = 16
+) -> tuple[int, ...]:
+    """Tree slots per row of each segment of ``boundaries`` (cumulative
+    ends, as :func:`padded_forest` takes them), padded to whole tree blocks
+    as :func:`padded_forest` pads them — shape arithmetic only, no buffers
+    are built."""
+    bt = _tree_block_for(ens, block_t)
+    starts = (0,) + tuple(boundaries[:-1])
+    return tuple(-(-(e - s) // bt) * bt for s, e in zip(starts, boundaries))
+
+
+def node_slots(n_nodes: int, words: int) -> int:
+    """Node axis of the kernel layout: the padded node count, and at least
+    one group of :data:`WORD_BITS` slots per mask word when the masks span
+    several words (:func:`_group_by_word`)."""
+    groups = WORD_BITS * words if words > 1 else 1
+    return _next_pow2(max(n_nodes, groups, 2))
+
+
+def _group_by_word(mask: np.ndarray) -> np.ndarray | None:
+    """Per tree, the node each slot of the kernel layout holds: ``[T,
+    N]`` indices into the node axis of a ``[T, N, W]`` mask padded to
+    :func:`node_slots`; ``None`` for single-word masks (the layout is the
+    node axis itself). Free slots take the last node, a padding node.
+
+    Node ``n`` zeroes leaves ``[lo, mid)``; its *top word* is the word of
+    leaf ``mid − 1``, the leaf just before ``n`` in in-order. That map is
+    one-to-one, so no word is the top word of more than
+    :data:`WORD_BITS` nodes of a tree, and group ``w`` — slots ``[32w,
+    32w + 32)`` — holds exactly the nodes whose top word is ``w``. A node
+    zeroes no word above its top word, and only a node whose span crosses
+    a word boundary zeroes one below it, so word ``w``'s AND needs group
+    ``w`` and the few groups holding such nodes (``word_columns``)
+    instead of every node. AND is order-free: the layout moves no bit of
+    the result.
+    """
+    T, N, W = mask.shape
+    if W == 1:
+        return None
+    zeroed = mask != ALL_ONES                                   # [T, N, W]
+    top = np.where(
+        zeroed.any(axis=2), W - 1 - np.argmax(zeroed[..., ::-1], axis=2), W
+    )                                                           # [T, N]
+    order = np.argsort(top, axis=1, kind="stable")
+    group = np.take_along_axis(top, order, axis=1)
+    counts = np.stack([(top == w).sum(axis=1) for w in range(W + 1)], 1)
+    starts = np.cumsum(counts, axis=1) - counts                 # [T, W+1]
+    rank = np.arange(N)[None, :] - np.take_along_axis(starts, group, 1)
+    real = group < W
+    assert (rank[real] < WORD_BITS).all(), "a mask word tops > 32 nodes"
+    assert not zeroed[:, -1].any(), "the last node slot is not padding"
+    src = np.full((T, N), N - 1, dtype=np.int32)
+    rows = np.broadcast_to(np.arange(T)[:, None], (T, N))
+    src[rows[real], (group * WORD_BITS + rank)[real]] = order[real]
+    return src
+
+
+def _take_nodes(table: jax.Array, src: jax.Array) -> jax.Array:
+    """``table[t, src[t, n], ...]``: a ``[T, N]`` or ``[T, N, W]`` table in
+    the node order ``src`` gives."""
+    if table.ndim == 3:
+        src = jnp.broadcast_to(src[..., None], table.shape)
+    return jnp.take_along_axis(table, src, axis=1)
+
+
+def _word_columns(
+    mask: np.ndarray, block_t: int
+) -> tuple[tuple[int, ...], ...]:
+    """Per mask word, the 128-lane columns of a node-major tree-block row
+    (slot ``n`` of tree ``t`` at lane ``n·BT + t``) that hold a node
+    zeroing that word in some tree."""
+    touched = (mask != ALL_ONES).any(axis=0)                    # [N, W]
+    column = np.arange(mask.shape[1]) * block_t // LANE
+    return tuple(
+        tuple(sorted(set(column[touched[:, w]].tolist())))
+        for w in range(mask.shape[2])
+    )
+
+
 def _build_padded_forest(
     ens: TreeEnsemble,
     cache: OrderedDict,
@@ -318,12 +414,24 @@ def _build_padded_forest(
     leaf_gather: str,
 ) -> PaddedForest:
     N = ens.feature.shape[1]
-    n_pad = _next_pow2(max(N, 2))
+    n_slots = node_slots(N, ens.mask.shape[2])
     # Padded nodes: threshold +inf ⇒ predicate always true ⇒ all-ones mask.
-    feat = _pad_to(ens.feature, 1, n_pad)
-    thr = _pad_to(ens.threshold.astype(jnp.float32), 1, n_pad, np.inf)
-    mlo = _pad_to(ens.mask_lo, 1, n_pad, ALL_ONES)
-    mhi = _pad_to(ens.mask_hi, 1, n_pad, ALL_ONES)
+    feat = _pad_to(ens.feature, 1, n_slots)
+    thr = _pad_to(ens.threshold.astype(jnp.float32), 1, n_slots, np.inf)
+    mask = _pad_to(ens.mask, 1, n_slots, ALL_ONES)
+    # The node order and each word's columns are static layout, read from
+    # the masks once per cached layout (at warm-up), never per engine call.
+    # repro: noqa(TS006) -- a cache-miss build, not a per-batch transfer
+    host_mask = np.asarray(mask)
+    # Multi-word masks: nodes grouped by the word they top, so that each
+    # word ANDs over its own columns.
+    src = _group_by_word(host_mask)
+    if src is not None:
+        feat, thr, mask = (
+            _take_nodes(a, jnp.asarray(src)) for a in (feat, thr, mask)
+        )
+        host_mask = np.take_along_axis(host_mask, src[..., None], axis=1)
+    word_columns = _word_columns(host_mask, block_t)
     leaf = ens.leaf_value.astype(jnp.float32)
     # Per-path leaf layout: the select tree's contiguous-half bit walk
     # needs a power-of-two leaf axis; pad values are 0 and unreachable
@@ -336,14 +444,13 @@ def _build_padded_forest(
         leaf_layout = "pow2"
 
     # Per-segment tree padding: no-op trees (always-true nodes, zero leaves).
-    parts = {name: [] for name in ("feat", "thr", "mlo", "mhi", "leaf")}
+    parts = {name: [] for name in ("feat", "thr", "mask", "leaf")}
     seg_block_starts, seg_blocks = [], []
     start = offset = 0
     for end in boundaries:
         parts["feat"].append(_pad_to(feat[start:end], 0, block_t))
         parts["thr"].append(_pad_to(thr[start:end], 0, block_t, np.inf))
-        parts["mlo"].append(_pad_to(mlo[start:end], 0, block_t, ALL_ONES))
-        parts["mhi"].append(_pad_to(mhi[start:end], 0, block_t, ALL_ONES))
+        parts["mask"].append(_pad_to(mask[start:end], 0, block_t, ALL_ONES))
         parts["leaf"].append(_pad_to(leaf[start:end], 0, block_t))
         nb = parts["feat"][-1].shape[0] // block_t
         seg_block_starts.append(offset)
@@ -354,14 +461,14 @@ def _build_padded_forest(
     pf = PaddedForest(
         feature=jnp.concatenate(parts["feat"]),
         threshold=jnp.concatenate(parts["thr"]),
-        mask_lo=jnp.concatenate(parts["mlo"]),
-        mask_hi=jnp.concatenate(parts["mhi"]),
+        mask=jnp.concatenate(parts["mask"]),
         leaf_value=jnp.concatenate(parts["leaf"]),
         base_score=ens.base_score,
         boundaries=boundaries,
         seg_block_starts=tuple(seg_block_starts),
         seg_blocks=tuple(seg_blocks),
         block_t=block_t,
+        word_columns=word_columns,
         leaf_gather=leaf_gather,
         leaf_layout=leaf_layout,
     )
@@ -423,11 +530,12 @@ def forest_score_range(
 
     scores = _counted_pallas(
         count_as, forest_score_pallas,
-        x, pf.feature, pf.threshold, pf.mask_lo, pf.mask_hi, pf.leaf_value,
+        x, pf.feature, pf.threshold, pf.mask, pf.leaf_value,
         block_b=block_b,
         block_t=pf.block_t,
         tree_block_offset=pf.seg_block_starts[seg_lo],
         n_tree_blocks=sum(pf.seg_blocks[seg_lo:seg_hi]),
+        word_columns=pf.word_columns,
         leaf_gather=pf.leaf_gather,
         interpret=interpret,
     )
@@ -457,11 +565,12 @@ def forest_score_segments(
 
     seg_scores = _counted_pallas(
         "segmented", forest_score_segments_pallas,
-        x, pf.feature, pf.threshold, pf.mask_lo, pf.mask_hi, pf.leaf_value,
+        x, pf.feature, pf.threshold, pf.mask, pf.leaf_value,
         seg_block_starts=pf.seg_block_starts[:S],
         n_tree_blocks=pf.seg_block_starts[S - 1] + pf.seg_blocks[S - 1],
         block_b=block_b,
         block_t=pf.block_t,
+        word_columns=pf.word_columns,
         leaf_gather=pf.leaf_gather,
         interpret=interpret,
     )

@@ -13,11 +13,16 @@ import jax.numpy as jnp
 ALL_ONES = jnp.uint32(0xFFFFFFFF)
 
 
-def _ctz64(hi: jax.Array, lo: jax.Array) -> jax.Array:
-    lo_nz = lo != 0
-    m = jnp.where(lo_nz, lo, hi)
-    ctz32 = jax.lax.population_count(~m & (m - jnp.uint32(1)))
-    return jnp.where(lo_nz, ctz32, ctz32 + jnp.uint32(32)).astype(jnp.int32)
+def _ctz_words(words: jax.Array) -> jax.Array:
+    """Trailing zeros of ``words[..., w]`` (bits ``[32w, 32w + 32)``)."""
+    leaf = None
+    for w in reversed(range(words.shape[-1])):
+        m = words[..., w]
+        ctz = jax.lax.population_count(~m & (m - jnp.uint32(1))).astype(
+            jnp.int32
+        ) + jnp.int32(32 * w)
+        leaf = ctz if leaf is None else jnp.where(m != 0, ctz, leaf)
+    return leaf
 
 
 def leaf_values_ref(leaf: jax.Array, leaf_value: jax.Array) -> jax.Array:
@@ -32,17 +37,14 @@ def forest_score_ref(
     x: jax.Array,
     feature: jax.Array,
     threshold: jax.Array,
-    mask_lo: jax.Array,
-    mask_hi: jax.Array,
+    mask: jax.Array,
     leaf_value: jax.Array,
 ) -> jax.Array:
-    """x: [B, F]; tree arrays [T, N] / [T, L] → scores [B] f32."""
+    """x: [B, F]; tree arrays [T, N] / [T, N, W] / [T, L] → scores [B] f32."""
     xf = x[:, feature]                                  # [B, T, N]
     pred_true = xf <= threshold[None]
-    m_lo = jnp.where(pred_true, ALL_ONES, mask_lo[None])
-    m_hi = jnp.where(pred_true, ALL_ONES, mask_hi[None])
-    and_lo = jax.lax.reduce(m_lo, ALL_ONES, jax.lax.bitwise_and, dimensions=(2,))
-    and_hi = jax.lax.reduce(m_hi, ALL_ONES, jax.lax.bitwise_and, dimensions=(2,))
-    leaf = _ctz64(and_hi, and_lo)                       # [B, T]
+    m = jnp.where(pred_true[..., None], ALL_ONES, mask[None])
+    words = jax.lax.reduce(m, ALL_ONES, jax.lax.bitwise_and, dimensions=(2,))
+    leaf = _ctz_words(words)                            # [B, T]
     per_tree = leaf_values_ref(leaf, leaf_value)
     return per_tree.sum(axis=1).astype(jnp.float32)

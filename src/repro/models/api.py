@@ -368,7 +368,7 @@ def _recsys_cell(cfg: RecSysConfig, shape: ShapeSpec) -> Cell:
 
 
 def _forest_abstract(cfg: ForestConfig):
-    from repro.forest.ensemble import TreeEnsemble
+    from repro.forest.ensemble import TreeEnsemble, mask_words
 
     n_int = (1 << cfg.depth) - 1
     n_leaf = 1 << cfg.depth
@@ -379,8 +379,7 @@ def _forest_abstract(cfg: ForestConfig):
             threshold=_sds((T, n_int), F32),
             left=_sds((T, n_int), I32),
             right=_sds((T, n_int), I32),
-            mask_lo=_sds((T, n_int), jnp.uint32),
-            mask_hi=_sds((T, n_int), jnp.uint32),
+            mask=_sds((T, n_int, mask_words(n_leaf)), jnp.uint32),
             leaf_value=_sds((T, n_leaf), F32),
             base_score=_sds((), F32),
         )
@@ -500,7 +499,7 @@ def _forest_cell(cfg: ForestConfig, shape: ShapeSpec) -> Cell:
             return TreeEnsemble(
                 feature=(None, None), threshold=(None, None),
                 left=(None, None), right=(None, None),
-                mask_lo=(None, None), mask_hi=(None, None),
+                mask=(None, None, None),
                 leaf_value=(None, None), base_score=(),
             )
 

@@ -75,7 +75,12 @@ from repro.core.lear import LearClassifier, augment_features
 from repro.core.stage import DenseStage, EngineConfig, TreeStage
 from repro.core.strategies import QueryExitConfig, dense_keep_fraction
 from repro.forest.ensemble import TreeEnsemble
-from repro.kernels.ops import ENGINE_BLOCK_B, launch_rows
+from repro.kernels.ops import (
+    ENGINE_BLOCK_B,
+    launch_rows,
+    resolve_leaf_gather,
+    segment_tree_slots,
+)
 from repro.metrics.speedup import progressive_cost_model
 from repro.serve.calibration import calibrate_launch_overhead_trees
 from repro.serve.tracing import ADAPT, DISPATCH, POST, READ, span
@@ -188,6 +193,9 @@ class ServiceStats:
     batches_staged: int = 0
     queries_exited: int = 0  # query-level exit fired (query_exit enabled)
     tail_rows: int = 0  # rows the tail launches scored, block padding included
+    # Document-tree slots the head, classifier and tail launches scored:
+    # rows times tree slots per launch, padded rows and tree blocks included.
+    doc_tree_slots: int = 0
     # Host seconds of rank_batch's phases (time.perf_counter): placement,
     # engine config and the compiled step's dispatch; what is left between
     # it and the read (unpacking only); the one device_get.
@@ -323,6 +331,12 @@ class RankingService:
             sentinel=stages[0].sentinel,
             strategy=self.stage_strategies[0],
             classifier_trees=stages[0].n_trees,
+        )
+        # The ranker's exit-leaf encoding, on every lear.step.dispatch span:
+        # mask words per node and the kernel's leaf-gather path.
+        self._dispatch_meta = dict(
+            mask_words=int(ensemble.mask.shape[2]),
+            leaf_gather=resolve_leaf_gather(ensemble.n_leaves),
         )
 
     def bucket_state(self, Q: int, D: int) -> _BucketAdaptState:
@@ -611,7 +625,7 @@ class RankingService:
         placement.
         """
         t0 = time.perf_counter()
-        with span(DISPATCH):
+        with span(DISPATCH, **self._dispatch_meta):
             if placement is not None:
                 X, mask = placement.put(X, mask)
             Q, D, _ = X.shape
@@ -621,6 +635,7 @@ class RankingService:
                     X, mask, engine_config, self.top_k, features=X, **extra,
                 )
                 tail_rows = self._tail_rows(engine_config, Q * D)
+                slots = self._doc_tree_slots(engine_config, Q * D)
         t1 = time.perf_counter()
         with span(POST):
             # All that is left between dispatch and read: the response and
@@ -683,6 +698,10 @@ class RankingService:
             s.trees_traversed += float(traversed)
             s.trees_full_equiv += int(batch_docs) * self.ensemble.n_trees
             s.tail_rows += 0 if tail_skipped else tail_rows
+            head_slots, tail_trees = slots[bool(picked_staged)], slots[2]
+            s.doc_tree_slots += head_slots + (
+                0 if tail_skipped else tail_rows * tail_trees
+            )
             s.dispatch_s += t1 - t0
             s.post_s += t2 - t1
             s.read_s += t3 - t2
@@ -696,6 +715,41 @@ class RankingService:
         if self.sentinels[-1] >= self.ensemble.n_trees:
             return 0
         return launch_rows(min(int(engine_config.capacities[-1]), n_docs))
+
+    def _doc_tree_slots(
+        self, engine_config: EngineConfig, n_docs: int
+    ) -> tuple[int, int, int]:
+        """Document-tree slots of one ``n_docs``-document batch, counted
+        from the launch shapes: the head and classifier launches of the
+        fused branch, the same of the staged branch, and the tail's tree
+        slots per row (its rows are :meth:`_tail_rows`). Rows are padded
+        as each launch pads them on the context mesh, trees to whole tree
+        blocks of each segment. Call with the placement's mesh in
+        context."""
+        T = self.ensemble.n_trees
+        sentinels = engine_config.sentinels
+        S = len(sentinels)
+        has_tail = sentinels[-1] < T
+        seg = segment_tree_slots(
+            self.ensemble, sentinels + ((T,) if has_tail else ()),
+            engine_config.block_t,
+        )
+        caps = [min(int(c), n_docs) for c in engine_config.capacities]
+        if engine_config.dense is not None:
+            head_rows, tree_caps = launch_rows(caps[0]), caps[1:]
+        else:
+            head_rows, tree_caps = launch_rows(n_docs), caps
+        classifiers = 0
+        if self.use_kernel_classifier:
+            classifiers = launch_rows(n_docs) * sum(
+                segment_tree_slots(c.forest, (c.forest.n_trees,))[0]
+                for c in self.stage_classifiers
+            )
+        fused = head_rows * sum(seg[:S]) + classifiers
+        staged = head_rows * seg[0] + classifiers + sum(
+            launch_rows(tree_caps[k]) * seg[k + 1] for k in range(S - 1)
+        )
+        return fused, staged, seg[S] if has_tail else 0
 
     def lower_step(
         self,

@@ -11,12 +11,16 @@ batcher worker opens them, one thread, nested as::
         lear.put                    host→device puts of the block and mask
         lear.step                   RankingService.rank_batch
             lear.step.dispatch      placement, engine config, compiled step
+                                    (mask_words, leaf_gather)
             lear.step.post          what is left before the read: unpacking
             lear.step.read          the one jax.device_get
             lear.step.adapt         survivor peaks, EMA and stats
         lear.scatter                per-request top-k and set_result
 
-Only ``lear.flush`` carries metadata. The device scopes of the compiled
+``lear.flush`` and ``lear.step.dispatch`` carry metadata; the dispatch's
+names the ranker's exit-leaf encoding: ``mask_words``, the uint32 words of
+a node's false-leaf mask (``ceil(n_leaves / 32)``), and ``leaf_gather``,
+the kernel's leaf-value path. The device scopes of the compiled
 step (``lear/head``, ``lear/features``, ``lear/classifier``,
 ``lear/compaction``, ``lear/tail``) are named where the step is built,
 :mod:`repro.core.cascade` (``SCOPE_*``), since the engine imports nothing

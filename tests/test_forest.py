@@ -14,7 +14,13 @@ from repro.forest import (
     train_gbdt,
     train_lambdamart,
 )
-from repro.forest.ensemble import random_ensemble, from_arrays
+from repro.forest.ensemble import (
+    MAX_LEAVES,
+    from_arrays,
+    from_complete_arrays,
+    random_ensemble,
+)
+from repro.kernels.ops import forest_score
 from repro.metrics.ranking import mean_ndcg
 
 
@@ -23,7 +29,7 @@ def rng():
     return np.random.default_rng(0)
 
 
-@pytest.mark.parametrize("depth", [1, 3, 6])
+@pytest.mark.parametrize("depth", [1, 3, 6, 8])
 @pytest.mark.parametrize("n_trees", [1, 17])
 def test_scorers_agree(rng, depth, n_trees):
     ens = random_ensemble(0, n_trees=n_trees, depth=depth, n_features=12)
@@ -53,6 +59,66 @@ def test_irregular_tree_from_arrays(rng):
     ref = score_numpy_oracle(ens, X)
     bv = np.asarray(score_bitvector(ens, jnp.asarray(X)))
     np.testing.assert_allclose(bv, ref, rtol=1e-5)
+
+
+def _lopsided_tree(rng, n_leaves, n_features):
+    """A random binary tree of exactly ``n_leaves`` leaves, its splits
+    drawn unevenly so that it is far from complete (pre-order internal
+    numbering; child < 0 encodes leaf slot -(i + 1))."""
+    f, t, lt, rt = [], [], [], []
+    leaves = [0]
+
+    def rec(n):
+        if n == 1:
+            leaves[0] += 1
+            return -leaves[0]
+        idx = len(f)
+        f.append(int(rng.integers(0, n_features)))
+        t.append(float(rng.normal()))
+        lt.append(0)
+        rt.append(0)
+        n_left = int(rng.integers(1, n)) if rng.random() < 0.5 else 1
+        lt[idx] = rec(n_left)
+        rt[idx] = rec(n - n_left)
+        return idx
+
+    rec(n_leaves)
+    return (np.asarray(f, np.int32), np.asarray(t, np.float32),
+            np.asarray(lt, np.int32), np.asarray(rt, np.int32),
+            (0.1 * rng.normal(size=n_leaves)).astype(np.float32))
+
+
+def test_unbalanced_256_leaf_tree_from_arrays(rng):
+    """Leaf-wise trees of 256 leaves (LightGBM's shape), far deeper than
+    8 levels on some paths: the 8-word masks and the kernel score them as
+    the per-document walk does."""
+    trees = [_lopsided_tree(rng, n, 10) for n in (256, 256, 129)]
+    ens = from_arrays(*(list(a) for a in zip(*trees)))
+    assert ens.n_leaves == 256 and ens.mask.shape == (3, 255, 8)
+    X = rng.normal(size=(96, 10)).astype(np.float32)
+    ref = score_numpy_oracle(ens, X)
+    np.testing.assert_allclose(np.asarray(score_bitvector(ens, jnp.asarray(X))),
+                               ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(forest_score(ens, jnp.asarray(X), interpret=True)),
+        ref, rtol=1e-5, atol=1e-5,
+    )
+
+
+def test_trees_past_the_mask_bound_raise(rng):
+    """Neither builder encodes a tree wider than MAX_LEAVES: both raise
+    instead of building masks that would score it wrongly."""
+    assert MAX_LEAVES == 256
+    tree = _lopsided_tree(rng, MAX_LEAVES + 1, 4)
+    with pytest.raises(ValueError, match="at most 256"):
+        from_arrays(*([a] for a in tree))
+    n_int = 2**9 - 1
+    with pytest.raises(ValueError, match="at most 256"):
+        from_complete_arrays(np.zeros((2, n_int), np.int32),
+                             np.zeros((2, n_int), np.float32),
+                             np.zeros((2, n_int + 1), np.float32))
+    with pytest.raises(ValueError, match="at most 256"):
+        random_ensemble(0, n_trees=1, depth=9, n_features=4)
 
 
 def test_partial_plus_tail_equals_full(rng):

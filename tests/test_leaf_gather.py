@@ -14,7 +14,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro.forest.ensemble import from_arrays, random_ensemble
-from repro.forest.scoring import score_numpy_oracle
+from repro.forest.scoring import score_level, score_numpy_oracle
 from repro.kernels.forest_score import (
     LEAF_GATHERS,
     forest_score_pallas,
@@ -26,7 +26,7 @@ from repro.kernels.ops import (
     padded_forest,
     resolve_leaf_gather,
 )
-from repro.kernels.ref import leaf_values_ref
+from repro.kernels.ref import forest_score_ref, leaf_values_ref
 
 
 def _score_all_paths(ens, X):
@@ -37,7 +37,7 @@ def _score_all_paths(ens, X):
     }
 
 
-@pytest.mark.parametrize("depth", [1, 3, 6])   # L = 2, 8, 64
+@pytest.mark.parametrize("depth", [1, 3, 6, 7, 8])   # L = 2 ... 256
 def test_paths_bitexact_pow2_leaves(depth):
     rng = np.random.default_rng(depth)
     ens = random_ensemble(depth, n_trees=24, depth=depth, n_features=20)
@@ -47,6 +47,15 @@ def test_paths_bitexact_pow2_leaves(depth):
         np.testing.assert_array_equal(got[lg], got["onehot"], err_msg=lg)
     np.testing.assert_allclose(
         got["onehot"], score_numpy_oracle(ens, X), rtol=1e-5, atol=1e-5
+    )
+    # The kernel's oracle (kernels/ref.py) and the structural walk.
+    ref = forest_score_ref(jnp.asarray(X), ens.feature, ens.threshold,
+                           ens.mask, ens.leaf_value)
+    np.testing.assert_allclose(got["onehot"], np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        got["onehot"], np.asarray(score_level(ens, jnp.asarray(X))),
+        rtol=1e-5, atol=1e-5,
     )
 
 
@@ -81,7 +90,9 @@ def _random_ragged_trees(rng, leaf_counts, n_features):
     return from_arrays(feats, thrs, lefts, rights, leaves)
 
 
-@pytest.mark.parametrize("leaf_counts", [(3, 5, 6, 4), (48, 33, 47, 21)])
+@pytest.mark.parametrize(
+    "leaf_counts", [(3, 5, 6, 4), (48, 33, 47, 21), (255, 97, 130)]
+)
 def test_paths_bitexact_non_pow2_leaves(leaf_counts):
     """Ragged ensembles give a non-power-of-two leaf axis: the select path
     must pad it (padded_forest leaf_layout='pow2') and still agree
@@ -102,9 +113,10 @@ def test_paths_bitexact_non_pow2_leaves(leaf_counts):
 
 
 def test_paths_bitexact_wide_leaf_table_L256():
-    """L=256 (the MXU-threshold regime): widen a depth-3 forest's leaf
-    table with junk columns — unreachable (every ctz leaf index < 8), so
-    all three paths must still return identical scores."""
+    """L=256 (the widest tree the builders take, at the select/MXU cutoff):
+    widen a depth-3 forest's leaf table with junk columns — unreachable
+    (every ctz leaf index < 8), so all three paths must still return
+    identical scores."""
     rng = np.random.default_rng(7)
     ens = random_ensemble(7, n_trees=16, depth=3, n_features=16)
     pf = padded_forest(ens, leaf_gather="onehot")
@@ -117,14 +129,15 @@ def test_paths_bitexact_wide_leaf_table_L256():
     x = jnp.asarray(rng.normal(size=(64, 16)).astype(np.float32))
     got = {
         lg: np.asarray(forest_score_pallas(
-            x, pf.feature, pf.threshold, pf.mask_lo, pf.mask_hi, wide_leaf,
+            x, pf.feature, pf.threshold, pf.mask, wide_leaf,
             block_b=64, block_t=pf.block_t, leaf_gather=lg, interpret=True,
         ))
         for lg in LEAF_GATHERS
     }
     for lg in ("select", "mxu"):
         np.testing.assert_array_equal(got[lg], got["onehot"], err_msg=lg)
-    assert resolve_leaf_gather(L) == "mxu"
+    assert resolve_leaf_gather(L) == "select"
+    assert resolve_leaf_gather(2 * L) == "mxu"
 
 
 def test_segmented_kernel_paths_bitexact():
@@ -150,7 +163,8 @@ def test_resolve_and_layout_policy():
     # Non-pow2 counts resolve on their padded width.
     assert resolve_leaf_gather(LEAF_SELECT_MAX - 1) == "select"
     assert resolve_leaf_gather(LEAF_SELECT_MAX + 1) == "mxu"
-    assert resolve_leaf_gather(256) == "mxu"
+    assert resolve_leaf_gather(256) == "select"   # the builders' widest
+    assert resolve_leaf_gather(512) == "mxu"
 
     ens = random_ensemble(13, n_trees=8, depth=3, n_features=8)
     auto = padded_forest(ens)

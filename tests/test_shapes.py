@@ -3,7 +3,7 @@ annotations (tier-1).
 
 ``shape_checked`` enforces the declared shapes/dtypes at call time with
 dim variables bound across arguments — the node axis ``n`` on
-``feature`` must be the SAME ``n`` as on ``threshold``/``mask_*``, and
+``feature`` must be the SAME ``n`` as on ``threshold``/``mask``, and
 the tree axis ``t`` must agree everywhere.  Production call sites stay
 unwrapped; this lane proves the annotations are truthful.
 """
@@ -29,8 +29,7 @@ def _operands():
         x=jnp.zeros((B, F), jnp.float32),
         feature=jnp.zeros((T, N), jnp.int32),
         threshold=jnp.zeros((T, N), jnp.float32),
-        mask_lo=jnp.full((T, N), 0xFFFFFFFF, jnp.uint32),
-        mask_hi=jnp.full((T, N), 0xFFFFFFFF, jnp.uint32),
+        mask=jnp.full((T, N, 1), 0xFFFFFFFF, jnp.uint32),
         leaf_value=jnp.zeros((T, L), jnp.float32),
     )
 

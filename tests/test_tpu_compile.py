@@ -37,6 +37,9 @@ from repro.utils import mosaic_kernel_rows
 CFG = lear_msn1.config()
 BLOCK_B = 256
 CLASSIFIER_DEPTH = 5   # repro.core.lear.train_lear's default
+#: lear-msn1-d8's widths (bench/configs/lear-msn1-d8.json): 500 complete
+#: trees of depth 8 over msn1's 136 features, sentinel 50.
+D8_TREES, D8_DEPTH = 500, 8
 
 
 @pytest.fixture(scope="module")
@@ -79,6 +82,13 @@ def ranker():
 
 
 @pytest.fixture(scope="module")
+def d8_ranker():
+    return random_ensemble(
+        0, n_trees=D8_TREES, depth=D8_DEPTH, n_features=CFG.n_features
+    )
+
+
+@pytest.fixture(scope="module")
 def classifier():
     return random_ensemble(
         1, n_trees=CFG.classifier_trees, depth=CLASSIFIER_DEPTH,
@@ -91,8 +101,7 @@ def _shapes(sharding, pf, rows, features):
     x = jax.ShapeDtypeStruct((rows, lanes), jnp.float32, sharding=sharding)
     tables = [
         jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
-        for a in (pf.feature, pf.threshold, pf.mask_lo, pf.mask_hi,
-                  pf.leaf_value)
+        for a in (pf.feature, pf.threshold, pf.mask, pf.leaf_value)
     ]
     return x, tables
 
@@ -105,7 +114,7 @@ def _compile_plain(sharding, pf, rows, features, **launch):
     x, tables = _shapes(sharding, pf, rows, features)
     fn = functools.partial(
         forest_score_pallas, block_t=pf.block_t, leaf_gather=pf.leaf_gather,
-        interpret=False, **launch,
+        word_columns=pf.word_columns, interpret=False, **launch,
     )
     return jax.jit(fn).lower(x, *tables).compile()
 
@@ -131,7 +140,7 @@ def test_segmented_head_launch(one_chip, ranker):
         seg_block_starts=pf.seg_block_starts[:2],
         n_tree_blocks=pf.seg_block_starts[1] + pf.seg_blocks[1],
         block_b=BLOCK_B, block_t=pf.block_t, leaf_gather=pf.leaf_gather,
-        interpret=False,
+        word_columns=pf.word_columns, interpret=False,
     )
     compiled = jax.jit(fn).lower(x, *tables).compile()
     assert _kernel_launches(compiled) == 1
@@ -183,12 +192,15 @@ def _lear_step_kernel_rows(*args, **kwargs) -> list[int]:
     return mosaic_kernel_rows(_lear_step_hlo(*args, **kwargs))
 
 
+@pytest.mark.parametrize("depth", [6, 8])
 def test_lear_serving_step_one_chip(monkeypatch, one_chip, ranker,
-                                    classifier):
+                                    d8_ranker, classifier, depth):
     """The whole jitted step: head, classifier and tail are Mosaic
-    kernels over the full (8, 256) batch."""
+    kernels over the full (8, 256) batch — for the paper's depth-6 trees
+    and for lear-msn1-d8's 256-leaf trees (8 mask words, blocks of 4)."""
     rows = _lear_step_kernel_rows(
-        monkeypatch, ranker, classifier, one_chip, 8, 256
+        monkeypatch, ranker if depth == 6 else d8_ranker, classifier,
+        one_chip, 8, 256,
     )
     assert rows == [8 * 256] * 3
 
@@ -217,20 +229,23 @@ def _mosaic_launches(hlo: str) -> list[tuple[str, str]]:
     return out
 
 
+@pytest.mark.parametrize("depth", [6, 8])
 @pytest.mark.parametrize("chips", [1, 4])
 def test_lear_step_launches_carry_their_stage_scope(monkeypatch, topo,
-                                                    ranker, classifier,
-                                                    chips):
+                                                    ranker, d8_ranker,
+                                                    classifier, chips,
+                                                    depth):
     """Each launch names its LEAR stage in its ``op_name`` metadata, and
-    keeps the instruction name the device trace's kernel match reads."""
+    keeps the instruction name the device trace's kernel match reads —
+    three ``forest_score_pallas`` launches at depth 6 and at depth 8."""
     if chips == 1:
         mesh, sharding = None, SingleDeviceSharding(topo.devices[0])
     else:
         mesh = make_mesh((4, 1), ("data", "model"),
                          devices=np.asarray(topo.devices[:4]))
         sharding = NamedSharding(mesh, PartitionSpec("data"))
-    hlo = _lear_step_hlo(monkeypatch, ranker, classifier, sharding, 8, 256,
-                         mesh=mesh)
+    hlo = _lear_step_hlo(monkeypatch, ranker if depth == 6 else d8_ranker,
+                         classifier, sharding, 8, 256, mesh=mesh)
     launches = _mosaic_launches(hlo)
     assert len(launches) == 3
     scopes = []

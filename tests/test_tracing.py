@@ -5,7 +5,9 @@
 - the span tree a real tier writes into a ``jax.profiler`` trace on the
   worker thread, read back with ``ProfileData``;
 - the service's ``tail_rows`` beside ``docs_continued`` for a known
-  bucket, and the rows a launch scores once padded to its blocks.
+  bucket, and the rows a launch scores once padded to its blocks;
+- the service's ``doc_tree_slots`` against a count worked out by hand
+  from the bucket's rows and each launch's padded tree blocks.
 """
 
 from __future__ import annotations
@@ -109,8 +111,8 @@ def test_an_expired_request_counts_no_queue_wait():
     assert (s.doc_slots, s.padded_doc_slots) == (8, 0)
 
 
-def _service(sentinel=8, n_trees=64):
-    ens = random_ensemble(0, n_trees=n_trees, depth=4, n_features=F)
+def _service(sentinel=8, n_trees=64, depth=4):
+    ens = random_ensemble(0, n_trees=n_trees, depth=depth, n_features=F)
     clf = LearClassifier(
         forest=random_ensemble(100, n_trees=10, depth=3, n_features=F + 4),
         sentinel=sentinel,
@@ -154,6 +156,38 @@ def test_tail_rows_beside_docs_continued(case, sentinel, seeded, tail_rows):
     assert s.docs_continued == 2 * 50
     assert s.tail_rows == 2 * tail_rows
     assert s.dispatch_s > 0 and s.post_s > 0 and s.read_s > 0
+
+
+@pytest.mark.parametrize(
+    "case, sentinel, seeded, depth, per_batch",
+    [
+        # 128 rows (one block of 128). Head: trees 0..8 padded to one
+        # block of 16; classifier: 10 trees padded to 16; tail: trees
+        # 8..64 padded to 64, on all 128 rows once warm-up seeds the peaks.
+        ("seeded", 8, True, 4, 128 * 16 + 128 * 16 + 128 * 64),
+        # Cold start: the tail scores 64 rows.
+        ("cold", 8, False, 4, 128 * 16 + 128 * 16 + 64 * 64),
+        # No tail: the head launch covers all 64 trees.
+        ("no_tail", 64, True, 4, 128 * 64 + 128 * 16),
+        # 256-leaf trees take blocks of 4 trees: the head's 8 trees fill
+        # two blocks, the tail's 56 fill fourteen.
+        ("depth8", 8, True, 8, 128 * 8 + 128 * 16 + 128 * 56),
+    ],
+)
+def test_doc_tree_slots_from_launch_shapes(case, sentinel, seeded, depth,
+                                           per_batch):
+    svc = _service(sentinel=sentinel, depth=depth)
+    if seeded:
+        svc.bucket_state(2, 64).peaks = [128]
+    X = np.random.default_rng(2).normal(size=(2, 64, F)).astype(np.float32)
+    X[..., 0] = -1.0
+    X[0, :30, 0] = 1.0
+    mask = np.zeros((2, 64), bool)
+    mask[0, :40] = True
+    mask[1, :] = True
+    svc.rank_batch(jnp.asarray(X), jnp.asarray(mask))
+    svc.rank_batch(jnp.asarray(X), jnp.asarray(mask))
+    assert svc.stats.doc_tree_slots == 2 * per_batch
 
 
 def test_launch_rows_pads_as_the_launch_does():
@@ -241,8 +275,10 @@ def test_worker_spans_nest_in_order(tmp_path):
         assert [e[0] for e in phases] == [
             tracing.DISPATCH, tracing.POST, tracing.READ, tracing.ADAPT]
         assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
-        # Only lear.flush carries metadata.
-        assert all(not e[3] for e in children + phases)
+        # Besides lear.flush, only lear.step.dispatch carries metadata:
+        # the ranker's mask words per node and leaf-gather path.
+        assert phases[0][3] == {"mask_words": 1, "leaf_gather": "select"}
+        assert all(not e[3] for e in children + phases[1:])
     assert flushes[1][3]["seq"] == flushes[0][3]["seq"] + 1
     waits = [e for e in events if e[0] == tracing.WAIT
              and flushes[0][2] <= e[1] and e[2] <= flushes[1][1]]
